@@ -4,7 +4,7 @@ zstd (violin-plot summary statistics: quartiles + mean)."""
 from __future__ import annotations
 
 import numpy as np
-from repro.core import zstd_compat as zstd
+import zstandard as zstd
 
 from benchmarks.common import Ctx, emit
 from repro.core.bitx import BitXCodec

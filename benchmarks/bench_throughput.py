@@ -23,9 +23,10 @@ import shutil
 import time
 
 import numpy as np
+import zstandard as zstd
 
 from benchmarks.common import Ctx, Timer, chain_copy, corpus_bytes, emit
-from repro.core import zstd_compat as zstd
+from repro.core.bitx import ENTROPY_BACKEND
 from repro.core.chunkdedup import ChunkDedup, FastCDC
 from repro.core.pipeline import ZLLMStore
 
@@ -440,7 +441,7 @@ def _assert_identical_containers(root_a: str, root_b: str) -> None:
 
 def run(ctx: Ctx, workers=(1, 4)) -> dict:
     total = corpus_bytes(ctx)
-    out = {"corpus_MB": round(total / 2**20, 1), "entropy_backend": zstd.BACKEND}
+    out = {"corpus_MB": round(total / 2**20, 1), "entropy_backend": ENTROPY_BACKEND}
 
     # --- zstd baseline (compression only) -------------------------------
     c = zstd.ZstdCompressor(level=3)

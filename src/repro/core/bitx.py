@@ -14,8 +14,8 @@ bit-identical:
   evaluation/throughput path, mirroring the paper's C++ engine);
 * ``jax`` — the Pallas kernels (``repro.kernels``), the TPU deployment path:
   same-width tensors are concatenated per bucket and transformed in ONE
-  fused kernel launch (interpret mode off-TPU, so tests validate the kernel
-  bodies on CPU). ``auto`` picks jax only when an accelerator is attached.
+  fused kernel launch (interpret mode on the CPU backend, so tests validate
+  the kernel bodies there). ``auto`` picks jax only when a TPU is attached.
 
 The per-codec encode/decode lanes live in the :mod:`repro.core.codecs`
 registry; :class:`BitXCodec` remains as a thin back-compat facade over it.
@@ -33,13 +33,13 @@ import json
 import mmap
 import os
 import struct
+import threading
 import warnings
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Protocol, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import zstd_compat as zstd
 from repro.core.codecs import CodecRuntime, EncodeInput, get_codec, raw_or_stored
 
 __all__ = [
@@ -48,6 +48,7 @@ __all__ = [
     "TensorRecord",
     "BitXWriter",
     "BitXReader",
+    "ENTROPY_BACKEND",
     "JaxBackend",
     "NumpyBackend",
     "TMP_SUFFIX",
@@ -59,6 +60,9 @@ __all__ = [
 
 MAGIC = b"BITX0001"
 DEFAULT_ZSTD_LEVEL = 3
+# Entropy coder stamped into every container header. Frames of another coder
+# are not interchangeable, so the reader refuses a container stamped otherwise.
+ENTROPY_BACKEND = "zstd"
 
 # Containers are written to ``<path>.part`` and atomically renamed into
 # place, so a crash mid-write can never leave a torn file at a path the
@@ -156,43 +160,69 @@ class ArrayBackend(Protocol):
     def byte_planes_batch(self, xs: Sequence[np.ndarray]) -> List[List[np.ndarray]]: ...
     def merge_planes_xor_batch(self, items: Sequence[Tuple[Sequence[np.ndarray], np.ndarray]]) -> List[np.ndarray]: ...
     def merge_planes_batch(self, items: Sequence[Tuple[Sequence[np.ndarray], np.dtype, Tuple[int, ...]]]) -> List[np.ndarray]: ...
+    def path_counts(self) -> Dict[str, int]: ...
 
 
-class NumpyBackend:
+class _PathCounts:
+    """Tensors and bytes each backend sent through the device kernels and
+    through the host path, since it was created (process-wide:
+    ``get_backend`` shares one instance per spec). Both backends report the
+    same four keys."""
+
+    def __init__(self):
+        self._counts_lock = threading.Lock()
+        self._counts = {"device_tensors": 0, "device_bytes": 0,
+                        "host_tensors": 0, "host_bytes": 0}
+
+    def _count(self, path: str, nbytes: Sequence[int]) -> None:
+        with self._counts_lock:
+            self._counts[path + "_tensors"] += len(nbytes)
+            self._counts[path + "_bytes"] += int(sum(nbytes))
+
+    def path_counts(self) -> Dict[str, int]:
+        with self._counts_lock:
+            return dict(self._counts)
+
+
+class NumpyBackend(_PathCounts):
     """Host path: strided-view plane splits on the ingest thread(s). Batched
     entry points degenerate to a loop — numpy gains nothing from fusion, and
     the pipeline only engages its batching stage for backends that declare
-    ``supports_batching``."""
+    ``supports_batching``. Every tensor counts as host path."""
 
     name = "numpy"
     supports_batching = False
 
     def xor_delta_planes(self, base, ft):
+        self._count("host", [np.asarray(ft).nbytes])
         return _xor_delta_planes_host(base, ft)
 
     def byte_planes(self, x):
+        self._count("host", [np.asarray(x).nbytes])
         return _byte_planes_host(x)
 
     def merge_planes_xor(self, planes, base):
+        self._count("host", [np.asarray(base).nbytes])
         return _merge_planes_xor_host(planes, base)
 
     def merge_planes(self, planes, dtype_np, shape):
+        self._count("host", [np.dtype(dtype_np).itemsize * int(np.prod(shape))])
         return _merge_planes_host(planes, dtype_np, shape)
 
     def xor_delta_planes_batch(self, pairs):
-        return [_xor_delta_planes_host(b, f) for b, f in pairs]
+        return [self.xor_delta_planes(b, f) for b, f in pairs]
 
     def byte_planes_batch(self, xs):
-        return [_byte_planes_host(x) for x in xs]
+        return [self.byte_planes(x) for x in xs]
 
     def merge_planes_xor_batch(self, items):
-        return [_merge_planes_xor_host(p, b) for p, b in items]
+        return [self.merge_planes_xor(p, b) for p, b in items]
 
     def merge_planes_batch(self, items):
-        return [_merge_planes_host(p, d, s) for p, d, s in items]
+        return [self.merge_planes(p, d, s) for p, d, s in items]
 
 
-class JaxBackend:
+class JaxBackend(_PathCounts):
     """Device path over the Pallas kernels (``repro.kernels.ops``).
 
     Inputs are converted to their unsigned bit views host-side (so int8 and
@@ -202,33 +232,26 @@ class JaxBackend:
     single launch, and per-tensor planes are sliced back out — bit-identical
     to the per-tensor host path because the transforms are elementwise.
 
-    Off-TPU the kernels execute in interpret mode (`ops._interpret`), which
-    is how the equivalence tests validate the kernel bodies on CPU. 8-byte
-    words fall back to the host implementation unless jax runs with x64
-    enabled (jax would silently truncate uint64 otherwise).
+    On the CPU backend the kernels execute in interpret mode
+    (`ops._interpret`), which is how the equivalence tests validate the
+    kernel bodies. 8-byte words take the host implementation unless jax runs
+    with x64 enabled (jax would silently truncate uint64 otherwise);
+    :meth:`path_counts` reports how many tensors and bytes took each path.
     """
 
     name = "jax"
     supports_batching = True
 
     def __init__(self, use_pallas: bool = True):
+        super().__init__()
         self.use_pallas = use_pallas
         self._ops_mod = None
 
-    @staticmethod
-    def available() -> bool:
-        import importlib.util
-        return importlib.util.find_spec("jax") is not None
-
     def _ops(self):
+        # imported on first use: the host-only store (and the entropy
+        # worker processes) never load jax
         if self._ops_mod is None:
-            try:
-                from repro.kernels import ops as ops_mod
-            except Exception as e:  # missing/broken jax toolchain
-                raise RuntimeError(
-                    "backend='jax' needs the jax/Pallas toolchain "
-                    "(repro.kernels.ops failed to import); construct the "
-                    "store with backend='numpy' or 'auto'") from e
+            from repro.kernels import ops as ops_mod
             self._ops_mod = ops_mod
         return self._ops_mod
 
@@ -270,9 +293,11 @@ class JaxBackend:
             views.append((a, b))
         for dstr, idxs in self._buckets([v[0].dtype for v in views]).items():
             if not self._device_ok(np.dtype(dstr)):
+                self._count("host", [views[i][0].nbytes for i in idxs])
                 for i in idxs:
                     out[i] = _xor_delta_planes_host(*views[i])
                 continue
+            self._count("device", [views[i][0].nbytes for i in idxs])
             cat_a = np.concatenate([views[i][0] for i in idxs])
             cat_b = np.concatenate([views[i][1] for i in idxs])
             planes = [np.asarray(p) for p in self._ops().bitx_encode_planes(
@@ -289,9 +314,11 @@ class JaxBackend:
         views = [_bit_view_np(np.ascontiguousarray(x)).reshape(-1) for x in xs]
         for dstr, idxs in self._buckets([v.dtype for v in views]).items():
             if not self._device_ok(np.dtype(dstr)):
+                self._count("host", [views[i].nbytes for i in idxs])
                 for i in idxs:
                     out[i] = _byte_planes_host(views[i])
                 continue
+            self._count("device", [views[i].nbytes for i in idxs])
             cat = np.concatenate([views[i] for i in idxs])
             planes = [np.asarray(p) for p in self._ops().zipnn_split_planes(
                 cat, use_pallas=self.use_pallas)]
@@ -307,9 +334,11 @@ class JaxBackend:
         views = [_bit_view_np(np.ascontiguousarray(base)) for _, base in items]
         for dstr, idxs in self._buckets([v.dtype for v in views]).items():
             if not self._device_ok(np.dtype(dstr)):
+                self._count("host", [views[i].nbytes for i in idxs])
                 for i in idxs:
                     out[i] = _merge_planes_xor_host(items[i][0], views[i])
                 continue
+            self._count("device", [views[i].nbytes for i in idxs])
             nb = np.dtype(dstr).itemsize
             cat_base = np.concatenate([views[i].reshape(-1) for i in idxs])
             cat_planes = [
@@ -332,10 +361,13 @@ class JaxBackend:
         for dstr, idxs in self._buckets(dtypes).items():
             dtype_np = np.dtype(dstr)
             nb = dtype_np.itemsize
+            sizes = [nb * int(np.prod(items[i][2])) for i in idxs]
             if not self._device_ok(dtype_np):
+                self._count("host", sizes)
                 for i in idxs:
                     out[i] = _merge_planes_host(*items[i])
                 continue
+            self._count("device", sizes)
             uview = np.dtype(f"<u{nb}")
             cat_planes = [
                 np.concatenate([np.ascontiguousarray(np.asarray(items[i][0][pi]))
@@ -361,10 +393,10 @@ def get_backend(spec="auto") -> ArrayBackend:
     """Resolve an array backend: ``"numpy"``, ``"jax"``, ``"auto"``, or an
     :class:`ArrayBackend` instance (passed through).
 
-    ``"auto"`` picks jax only when an accelerator is actually attached
-    (``jax.default_backend() != "cpu"``) — on CPU-only boxes the numpy host
-    path wins by a wide margin (interpret-mode kernels are Python emulation),
-    so auto-fallback keeps ingest throughput unregressed.
+    ``"auto"`` picks jax only when a TPU is attached
+    (``jax.default_backend() == "tpu"``); elsewhere the numpy host path runs
+    (interpret-mode kernels are Python emulation). A JAX that fails to
+    initialise raises here: it never turns into the host path unnoticed.
     """
     if not isinstance(spec, str):
         return spec
@@ -376,14 +408,8 @@ def get_backend(spec="auto") -> ArrayBackend:
     elif spec == "jax":
         backend = JaxBackend()
     elif spec == "auto":
-        backend = NumpyBackend()
-        if JaxBackend.available():
-            try:
-                import jax
-                if jax.default_backend() != "cpu":
-                    backend = JaxBackend()
-            except Exception:
-                pass  # broken jax install: the host path always works
+        import jax
+        backend = JaxBackend() if jax.default_backend() == "tpu" else NumpyBackend()
     else:
         raise ValueError(f"unknown array backend {spec!r} "
                          f"(expected 'numpy', 'jax' or 'auto')")
@@ -612,7 +638,7 @@ class BitXWriter:
     def tobytes(self) -> bytes:
         header = {
             "metadata": self.file_metadata,
-            "backend": zstd.BACKEND,
+            "backend": ENTROPY_BACKEND,
             "tensors": [r.to_json() for r in self.records],
         }
         hjson = json.dumps(header, separators=(",", ":")).encode()
@@ -676,11 +702,11 @@ class BitXReader:
         assert bytes(view[:8]) == MAGIC, "not a BitX container"
         (hlen,) = struct.unpack("<Q", view[8:16])
         header = json.loads(bytes(view[16 : 16 + hlen]))
-        backend = header.get("backend", zstd.BACKEND)
-        if backend != zstd.BACKEND:
+        backend = header.get("backend", ENTROPY_BACKEND)
+        if backend != ENTROPY_BACKEND:
             raise ValueError(
                 f"container written with entropy backend {backend!r} but this "
-                f"process runs {zstd.BACKEND!r} (see repro.core.zstd_compat)")
+                f"store decodes only {ENTROPY_BACKEND!r} frames")
         self.file_metadata: Dict = header.get("metadata", {})
         self.records = [TensorRecord.from_json(r) for r in header["tensors"]]
         self._name_to_idx: Optional[Dict[str, int]] = None

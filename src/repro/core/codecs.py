@@ -41,7 +41,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import zstd_compat as zstd
+import zstandard as zstd
 
 __all__ = [
     "Codec",

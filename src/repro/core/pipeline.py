@@ -19,7 +19,7 @@ header blob + decoded tensors in serialization order, verified against the
 ingest-time file hash).
 
 Parallel engine (paper §4.4.5 — the C++ pipeline, reproduced here with a
-thread pool; sha256, zstd/zlib and numpy's XOR all release the GIL):
+thread pool; sha256, zstd and numpy's XOR all release the GIL):
 
 * **Ingest** is a three-stage pipeline per file. Stage 1 fans per-tensor
   sha256 hashing out across the pool. Stage 2 — the *decision loop* — runs
@@ -73,7 +73,9 @@ library — ``repro.serve.store_server`` builds directly on these pieces):
   stage — where thread scaling is capped by the measured
   ``hardware_thread_ceiling`` — ships plane bytes to worker processes;
   frames are pure functions of (bytes, level, threads), so containers stay
-  bit-identical. Broken/missing fork support degrades to threads.
+  bit-identical. Workers start with ``spawn`` (a forked child would inherit
+  the parent's hold on the accelerator) and never initialise a JAX backend;
+  a pool that cannot start, or a worker that fails, raises.
 * **Pin-counted readers:** the reader LRU stores pinned handles; eviction
   (overflow, gc, quarantine) closes the mmap deterministically when idle or
   at the last in-flight release — no fd accumulation under churn, and never
@@ -140,9 +142,11 @@ import base64
 import bisect
 import itertools
 import json
+import multiprocessing
 import os
 import queue
 import struct
+import sys
 import threading
 import time
 import zlib
@@ -153,8 +157,8 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
+import zstandard as zstd
 
-from repro.core import zstd_compat as zstd
 from repro.core.bitx import (TMP_SUFFIX, BitXReader, BitXWriter, get_backend)
 from repro.core.clustering import FamilyRegistry
 from repro.core.codecs import CodecRuntime, EncodeInput, get_codec, raw_or_stored
@@ -177,6 +181,22 @@ def _entropy_compress(level: int, threads: int, blobs: List[bytes]) -> List[byte
     child process cannot change the emitted container bytes."""
     c = zstd.ZstdCompressor(level=level, threads=threads)
     return [c.compress(b) for b in blobs]
+
+
+def _entropy_worker_holds_jax() -> bool:
+    """True when this process has initialised a JAX backend (an entropy
+    worker would then contend for the accelerator its parent holds)."""
+    xb = sys.modules.get("jax._src.xla_bridge")
+    return xb is not None and xb.backends_are_initialized()
+
+
+def _entropy_worker_init() -> None:
+    """Initializer of every entropy worker process. It raises in a worker
+    that holds a JAX backend; a raising initializer breaks the pool, so
+    every later submit fails instead of running."""
+    if _entropy_worker_holds_jax():
+        raise RuntimeError("entropy worker initialised a JAX backend "
+                           "(its parent holds the device)")
 
 # v1 = PR-1 (no generations); v2 adds lifecycle + pinned gens; v3 adds the
 # incremental-GC cursor + compaction state (compact-pool versions travel in
@@ -253,10 +273,11 @@ _QDELTA_BASE_TAGS = {"BF16", "F32", "F16"}
 # per-task overhead without hurting parallel coverage.
 _PARALLEL_MIN_BYTES = 64 << 10
 
-# Device-batched encode (backends with ``supports_batching``): the plan loop
-# accumulates bitx/zipnn tensors and flushes once a batch holds this many raw
-# bytes, bounding the host copies of the concatenated bit views that feed the
-# fused kernel launches.
+# Device-batched encode and decode (backends with ``supports_batching``): the
+# plan loop accumulates bitx/zipnn tensors and flushes once a batch holds this
+# many raw bytes, and container decode merges records in groups of the same
+# bound. It caps the host copies of the concatenated bit views and the device
+# memory of each fused kernel launch.
 _DEVICE_BATCH_MAX_BYTES = 256 << 20
 
 
@@ -332,6 +353,8 @@ class StoreStats:
     # compactions fired by an AutoCompactPolicy watermark (subset of
     # compact_runs): the soak asserts the trigger actually fires
     auto_compact_runs: int = 0
+    # raw bytes ingested into containers, per final codec of each record
+    codec_bytes: Dict[str, int] = field(default_factory=dict)
 
     @property
     def reduction_ratio(self) -> float:
@@ -644,7 +667,6 @@ class ZLLMStore:
         self._pool: Optional[ThreadPoolExecutor] = None
         self._writer_pool: Optional[ThreadPoolExecutor] = None
         self._entropy_pool: Optional[ProcessPoolExecutor] = None
-        self._entropy_failed = False
         self._cache_lock = threading.RLock()
         # readers are pin-counted handles: eviction retires a handle and the
         # mmap closes deterministically once the last in-flight decode
@@ -724,23 +746,24 @@ class ZLLMStore:
         return self._writer_pool
 
     def _entropy_executor(self) -> Optional[ProcessPoolExecutor]:
-        """Opt-in process pool for the entropy stage. Gated: sandboxes
-        without working fork/spawn fall back to in-thread compression (the
-        containers stay bit-identical either way)."""
-        if self.entropy_procs <= 0 or self._entropy_failed:
+        """Opt-in process pool for the entropy stage. Workers are spawned,
+        not forked: a fork would copy the parent's accelerator client. Each
+        worker checks itself in ``_entropy_worker_init``; a first task
+        surfaces a pool that cannot start here, not mid-encode, and raises.
+        It never degrades to threads."""
+        if self.entropy_procs <= 0:
             return None
         if self._entropy_pool is None:
-            pool = None
+            pool = ProcessPoolExecutor(
+                max_workers=self.entropy_procs,
+                mp_context=multiprocessing.get_context("spawn"),
+                initializer=_entropy_worker_init)
             try:
-                pool = ProcessPoolExecutor(max_workers=self.entropy_procs)
-                # probe: surface broken process spawning here, not mid-encode
-                pool.submit(_entropy_compress, 1, 0, [b""]).result(timeout=60)
-                self._entropy_pool = pool
-            except Exception:
-                self._entropy_failed = True
-                if pool is not None:  # reap any workers the probe spawned
-                    pool.shutdown(wait=False, cancel_futures=True)
-                return None
+                pool.submit(_entropy_compress, 1, 0, [b""]).result(timeout=120)
+            except BaseException:
+                pool.shutdown(wait=False, cancel_futures=True)
+                raise
+            self._entropy_pool = pool
         return self._entropy_pool
 
     def close(self):
@@ -1083,6 +1106,9 @@ class ZLLMStore:
         pw.res.stored_bytes = stored
         pw.res.ingest_seconds = time.perf_counter() - pw.pf.t0
         self.lifecycle.set_nbytes(pw.key, pw.gen, stored)
+        for r in pw.writer.records:
+            self.stats.codec_bytes[r.codec] = (
+                self.stats.codec_bytes.get(r.codec, 0) + r.raw_size)
         self._account_stats(pw.res)
 
     def _rollback_failed_write(self, pw: "_PendingWrite") -> None:
@@ -1566,16 +1592,8 @@ class ZLLMStore:
 
     def _entropy_frames(self, epool: ProcessPoolExecutor,
                         blobs: List[bytes]) -> List[bytes]:
-        try:
-            return epool.submit(_entropy_compress, self.zstd_level,
-                                self.zstd_threads, blobs).result()
-        except Exception:
-            # broken child pool mid-run: fall back to in-thread entropy —
-            # the frames are identical, only the executor changes
-            self._entropy_failed = True
-            c = zstd.ZstdCompressor(level=self.zstd_level,
-                                    threads=self.zstd_threads)
-            return [c.compress(b) for b in blobs]
+        return epool.submit(_entropy_compress, self.zstd_level,
+                            self.zstd_threads, blobs).result()
 
     # ------------------------------------------------------------------
     def _resolve_base(self, repo_id: str, path: str,
@@ -2211,45 +2229,60 @@ class ZLLMStore:
 
     def _decode_records_batched(self, reader: BitXReader) -> List[bytes]:
         """Decode a whole container with the array stage bucketed into fused
-        device launches: plane frames entropy-decode across the pool
-        (order-preserving map), bases resolve serially, then ONE
-        ``merge_planes_xor_batch`` / ``merge_planes_batch`` call covers every
-        bitx / zipnn record; the remaining codecs decode per-record. The
-        merges are elementwise, so the output bytes are identical to the
-        per-record path."""
+        device launches: the plane frames of every bitx/zipnn record
+        entropy-decode across the pool (order-preserving map), and as they
+        arrive the records are merged in groups of at most
+        ``_DEVICE_BATCH_MAX_BYTES`` raw bytes (the ingest flush bound, which
+        caps the device memory of each launch): bases resolve serially, then
+        ONE ``merge_planes_xor_batch`` / ``merge_planes_batch`` call covers
+        the group's bitx / zipnn records. The remaining codecs decode
+        per-record. The merges are elementwise, so the output bytes are
+        identical to the per-record path."""
         rt = self._codec_runtime
         records = reader.records
         out: List[Optional[bytes]] = [None] * len(records)
-        bitx_idx = [i for i, r in enumerate(records) if r.codec == "bitx"]
-        zip_idx = [i for i, r in enumerate(records) if r.codec == "zipnn"]
+        pool = self._executor()
+        resolver = self._resolve_tensor_hash
 
         def planes_for(i: int) -> List[np.ndarray]:
             return [np.frombuffer(rt.decompress(bytes(f)), np.uint8)
                     for f in reader.frames_for(i)]
 
-        idxs = bitx_idx + zip_idx
-        pool = self._executor()
+        def merge_group(planes_of: Dict[int, List[np.ndarray]]) -> None:
+            bitx_idx = [i for i in planes_of if records[i].codec == "bitx"]
+            zip_idx = [i for i in planes_of if records[i].codec == "zipnn"]
+            if bitx_idx:
+                items = []
+                for i in bitx_idx:
+                    base = resolver(records[i].base_hash)
+                    if isinstance(base, (bytes, memoryview)):
+                        base = np.frombuffer(base, STR_TO_DTYPE[records[i].dtype_str])
+                    items.append((planes_of[i], base.reshape(-1)))
+                for i, merged in zip(bitx_idx,
+                                     self.backend.merge_planes_xor_batch(items)):
+                    out[i] = np.ascontiguousarray(
+                        merged.reshape(records[i].shape)).tobytes()
+            if zip_idx:
+                items = [(planes_of[i], STR_TO_DTYPE[records[i].dtype_str],
+                          records[i].shape) for i in zip_idx]
+                for i, merged in zip(zip_idx, self.backend.merge_planes_batch(items)):
+                    out[i] = np.ascontiguousarray(merged).tobytes()
+
+        idxs = [i for i, r in enumerate(records) if r.codec in ("bitx", "zipnn")]
         if pool is not None and len(idxs) > 1:
-            planes_of = dict(zip(idxs, pool.map(planes_for, idxs)))
+            planes_iter = pool.map(planes_for, idxs)
         else:
-            planes_of = {i: planes_for(i) for i in idxs}
-        resolver = self._resolve_tensor_hash
-        if bitx_idx:
-            items = []
-            for i in bitx_idx:
-                base = resolver(records[i].base_hash)
-                if isinstance(base, (bytes, memoryview)):
-                    base = np.frombuffer(base, STR_TO_DTYPE[records[i].dtype_str])
-                items.append((planes_of[i], base.reshape(-1)))
-            for i, merged in zip(bitx_idx,
-                                 self.backend.merge_planes_xor_batch(items)):
-                out[i] = np.ascontiguousarray(
-                    merged.reshape(records[i].shape)).tobytes()
-        if zip_idx:
-            items = [(planes_of[i], STR_TO_DTYPE[records[i].dtype_str],
-                      records[i].shape) for i in zip_idx]
-            for i, merged in zip(zip_idx, self.backend.merge_planes_batch(items)):
-                out[i] = np.ascontiguousarray(merged).tobytes()
+            planes_iter = map(planes_for, idxs)
+        group: Dict[int, List[np.ndarray]] = {}
+        group_bytes = 0
+        for i, planes in zip(idxs, planes_iter):
+            group[i] = planes
+            group_bytes += records[i].raw_size
+            if group_bytes >= _DEVICE_BATCH_MAX_BYTES:
+                merge_group(group)
+                group, group_bytes = {}, 0
+        if group:
+            merge_group(group)
         for i in range(len(records)):
             if out[i] is None:  # dedup / raw / stored / bitxq (never batched)
                 arr = reader.decode_tensor(i, resolver, resolver)
@@ -3589,6 +3622,10 @@ class ZLLMStore:
     def summary(self) -> Dict:
         return {
             "array_backend": self.backend.name,
+            # tensors/bytes through the device kernels vs the host path
+            "array_path": self.backend.path_counts(),
+            # raw bytes ingested per final codec (dedup/bitx/zipnn/...)
+            "codec_bytes": dict(self.stats.codec_bytes),
             "n_files": self.stats.n_files,
             "raw_bytes": self.stats.raw_bytes,
             "stored_bytes": self.stats.stored_bytes,
@@ -3620,7 +3657,7 @@ class ZLLMStore:
             "retrieval_caches": self.retrieval_cache_stats,
             "workers": self.workers,
             "pipeline_depth": self.pipeline_depth,
-            "entropy_procs": 0 if self._entropy_failed else self.entropy_procs,
+            "entropy_procs": self.entropy_procs,
             "read_gen": self.read_gen,
             "ingest_throughput_MBps": round(self.stats.ingest_throughput_mbps, 1),
         }

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for the zLLM storage layer (+ beyond-paper compute).
+"""Pallas TPU kernels for the zLLM storage layer.
 
 Storage-path kernels (the paper's hot loops; pipeline context in
 docs/ARCHITECTURE.md):
@@ -6,13 +6,11 @@ docs/ARCHITECTURE.md):
   hamming.py      — fused XOR + popcount + two-stage reduce (bit distance)
   byte_planes.py  — ZipNN byte-plane shuffle (the no-family fallback)
 
-Beyond-paper compute kernel:
-  flash_attention.py — fwd flash attention, VMEM-resident score blocks
-
 Each kernel pairs with a pure-jnp oracle in ``ref.py``; ``ops.py`` is the
-public jit'd API. On non-TPU backends kernels run in interpret mode; tests
-sweep shapes/dtypes asserting exact (bit ops) or tight-tolerance (attention)
-agreement with the oracles.
+public jit'd API. On the CPU backend kernels run in interpret mode; tests
+sweep shapes/dtypes asserting exact agreement with the oracles, and
+tests/test_tpu_compile.py compiles the storage-path kernels for a described
+TPU v5e.
 
 These kernels are LIVE in the storage pipeline, reached through two layers
 of indirection rather than called directly: the pipeline dispatches every

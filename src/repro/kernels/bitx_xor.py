@@ -28,9 +28,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 __all__ = [
-    "xor_2d",
     "xor_split_2d",
     "merge_xor_2d",
+    "split_planes",
+    "merge_planes",
     "DEFAULT_BLOCK_ROWS",
     "LANES",
 ]
@@ -39,59 +40,42 @@ LANES = 1024  # second-minor tile dim; multiple of the 128-lane VPU width
 DEFAULT_BLOCK_ROWS = 256
 
 
-def _xor_kernel(a_ref, b_ref, o_ref):
-    o_ref[...] = jnp.bitwise_xor(a_ref[...], b_ref[...])
+# Mosaic has no 16-bit shifts (``arith.shrui``/``arith.shli`` on i16 fail to
+# legalize), so plane extraction and assembly run on words widened to 32
+# bits; the narrowing casts at either end are exact.
+
+def split_planes(word: jax.Array, plane_refs) -> None:
+    """Write the byte planes of ``word`` (MSB first) into ``plane_refs``."""
+    w = word.astype(jnp.uint32)
+    nb = len(plane_refs)
+    for i, p_ref in enumerate(plane_refs):
+        p_ref[...] = jnp.right_shift(w, jnp.uint32(8 * (nb - 1 - i))).astype(jnp.uint8)
+
+
+def merge_planes(plane_refs) -> jax.Array:
+    """Assemble byte planes (MSB first) into a uint32 word."""
+    nb = len(plane_refs)
+    w = jnp.zeros(plane_refs[0].shape, jnp.uint32)
+    for i, p_ref in enumerate(plane_refs):
+        w = jnp.bitwise_or(w, jnp.left_shift(p_ref[...].astype(jnp.uint32),
+                                             jnp.uint32(8 * (nb - 1 - i))))
+    return w
 
 
 def _xor_split_kernel(a_ref, b_ref, *plane_refs):
     """XOR + byte-plane split, MSB plane first."""
-    delta = jnp.bitwise_xor(a_ref[...], b_ref[...])
-    nb = len(plane_refs)
-    for i, p_ref in enumerate(plane_refs):
-        k = nb - 1 - i
-        p_ref[...] = jnp.right_shift(delta, jnp.array(8 * k, delta.dtype)).astype(jnp.uint8)
+    split_planes(jnp.bitwise_xor(a_ref[...], b_ref[...]), plane_refs)
 
 
 def _merge_xor_kernel(base_ref, *refs):
     """planes (MSB first) + base -> ft bits. Last ref is the output."""
     plane_refs, o_ref = refs[:-1], refs[-1]
-    dtype = base_ref.dtype
-    nb = len(plane_refs)
-    delta = jnp.zeros(base_ref.shape, dtype)
-    for i, p_ref in enumerate(plane_refs):
-        k = nb - 1 - i
-        delta = jnp.bitwise_or(
-            delta, jnp.left_shift(p_ref[...].astype(dtype), jnp.array(8 * k, dtype))
-        )
+    delta = merge_planes(plane_refs).astype(o_ref.dtype)
     o_ref[...] = jnp.bitwise_xor(delta, base_ref[...])
 
 
 def _row_blockspec(block_rows: int, cols: int) -> pl.BlockSpec:
     return pl.BlockSpec((block_rows, cols), lambda i: (i, 0))
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def xor_2d(
-    a: jax.Array,
-    b: jax.Array,
-    *,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = False,
-) -> jax.Array:
-    """Element-wise XOR over a 2D (rows, LANES-multiple) bit view."""
-    rows, cols = a.shape
-    block_rows = min(block_rows, rows)
-    assert rows % block_rows == 0, (rows, block_rows)
-    grid = (rows // block_rows,)
-    spec = _row_blockspec(block_rows, cols)
-    return pl.pallas_call(
-        _xor_kernel,
-        out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
-        in_specs=[spec, spec],
-        out_specs=spec,
-        grid=grid,
-        interpret=interpret,
-    )(a, b)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))

@@ -20,30 +20,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.bitx_xor import DEFAULT_BLOCK_ROWS
+from repro.kernels.bitx_xor import DEFAULT_BLOCK_ROWS, merge_planes, split_planes
 
 __all__ = ["split_2d", "merge_2d"]
 
 
 def _split_kernel(x_ref, *plane_refs):
-    x = x_ref[...]
-    nb = len(plane_refs)
-    for i, p_ref in enumerate(plane_refs):
-        k = nb - 1 - i  # MSB plane first
-        p_ref[...] = jnp.right_shift(x, jnp.array(8 * k, x.dtype)).astype(jnp.uint8)
+    split_planes(x_ref[...], plane_refs)
 
 
 def _merge_kernel(*refs):
     plane_refs, o_ref = refs[:-1], refs[-1]
-    dtype = o_ref.dtype
-    nb = len(plane_refs)
-    out = jnp.zeros(o_ref.shape, dtype)
-    for i, p_ref in enumerate(plane_refs):
-        k = nb - 1 - i
-        out = jnp.bitwise_or(
-            out, jnp.left_shift(p_ref[...].astype(dtype), jnp.array(8 * k, dtype))
-        )
-    o_ref[...] = out
+    o_ref[...] = merge_planes(plane_refs).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))

@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "mha_reference",
     "xor_bits",
     "xor_split_planes",
     "merge_planes_xor",
@@ -101,25 +100,3 @@ def hamming_total(a: jax.Array, b: jax.Array) -> jax.Array:
     assert a.shape == b.shape and a.dtype == b.dtype
     pc = jax.lax.population_count(jnp.bitwise_xor(a, b))
     return jnp.sum(pc.astype(jnp.uint32), dtype=jnp.uint32)
-
-
-def mha_reference(q, k, v, *, causal=True, window=0):
-    """Dense masked softmax attention oracle for the flash kernel.
-
-    q: (B, Sq, H, D); k, v: (B, Sk, H, D). fp32 softmax, output in q.dtype.
-    """
-    import jax.numpy as jnp
-    B, Sq, H, D = q.shape
-    Sk = k.shape[1]
-    s = jnp.einsum("bqhd,bshd->bhqs", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) / (D ** 0.5)
-    qi = jnp.arange(Sq)[:, None]
-    kj = jnp.arange(Sk)[None, :]
-    mask = jnp.ones((Sq, Sk), bool)
-    if causal:
-        mask &= kj <= qi
-    if window:
-        mask &= kj > qi - window
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqs,bshd->bqhd", p, v.astype(jnp.float32)).astype(q.dtype)

@@ -18,17 +18,12 @@ FSDP; "pod" carries DP (and optionally FSDP for grok-scale models — see
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "HW"]
 
 
 def _mk(shape, axes):
-    # jax >= 0.4.35 exposes AxisType; older releases (this container ships
-    # 0.4.x without it) accept plain make_mesh with default axis types
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

@@ -1615,6 +1615,8 @@ def main(argv=None) -> int:
                          "replica groups then span server processes")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
     router = StoreRouter.open_roots(args.root, workers=args.store_workers,
                                     replicas=args.replicas,
                                     write_quorum=args.write_quorum,

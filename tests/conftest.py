@@ -7,8 +7,6 @@ os.environ.pop("XLA_FLAGS", None)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-# tests import sibling helpers (_hypothesis_compat) without a package prefix
-sys.path.insert(0, os.path.dirname(__file__))
 
 import numpy as np
 import pytest

@@ -1,11 +1,9 @@
-"""Entropy-backend compatibility (repro.core.zstd_compat).
+"""Entropy-backend stamp of ``.bitx`` containers.
 
-A ``.bitx`` container stamps the backend that wrote it (``zstd`` or the
-``zlib`` fallback). Frames from the two are NOT interchangeable, so opening
-a container under the other backend must raise a clear, actionable error —
-never hand back garbage bytes. These tests run on both CI matrix legs: each
-leg writes with ITS backend and forges the other stamp, so the
-zstd-container-in-zlib-env case and its mirror are both exercised.
+A container stamps the entropy coder that wrote it (``zstd``). Frames of
+another coder are NOT interchangeable, so opening a container stamped with
+any other backend must raise a clear, actionable error — never hand back
+garbage bytes. The tests write with zstd and forge another stamp.
 """
 
 import json
@@ -15,18 +13,17 @@ import struct
 import numpy as np
 import pytest
 
-from repro.core import zstd_compat as zstd
-from repro.core.bitx import MAGIC, BitXReader, BitXWriter
+from repro.core.bitx import ENTROPY_BACKEND, MAGIC, BitXReader, BitXWriter
 from repro.core.pipeline import ZLLMStore
 from repro.formats import safetensors as st
 
-OTHER_BACKEND = "zlib" if zstd.BACKEND == "zstd" else "zstd"
+OTHER_BACKEND = "zlib"
 
 
 def _restamp_backend(path: str, backend: str) -> None:
     """Rewrite a container's header with a forged entropy-backend stamp
-    (payload untouched) — simulating a container produced in an env with
-    the other backend installed."""
+    (payload untouched) — simulating a container produced by another
+    entropy coder."""
     raw = open(path, "rb").read()
     assert raw[:8] == MAGIC
     (hlen,) = struct.unpack("<Q", raw[8:16])
@@ -61,9 +58,8 @@ def test_backend_mismatch_raises_clear_error(tmp_path):
     with pytest.raises(ValueError) as ei:
         BitXReader.open(path)
     msg = str(ei.value)
-    # the error must name both backends and point at the shim
-    assert OTHER_BACKEND in msg and zstd.BACKEND in msg
-    assert "zstd_compat" in msg
+    # the error must name both backends
+    assert OTHER_BACKEND in msg and ENTROPY_BACKEND in msg
 
 
 def test_store_retrieval_surfaces_backend_mismatch_not_garbage(tmp_path):

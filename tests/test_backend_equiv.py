@@ -11,11 +11,8 @@ import os
 import numpy as np
 import pytest
 
-from repro.core.bitx import JaxBackend, NumpyBackend, get_backend
+from repro.core.bitx import NumpyBackend, get_backend
 from repro.core.pipeline import ZLLMStore
-
-pytestmark = pytest.mark.skipif(not JaxBackend.available(),
-                                reason="jax not installed")
 
 NP = NumpyBackend()
 
@@ -159,16 +156,47 @@ def test_store_containers_bit_identical_numpy_vs_jax(tmp_path, corpus_dir):
         s.close()
 
 
+def test_grouped_container_decode_matches_source(tmp_path, corpus_dir, monkeypatch):
+    """With a decode bound below one tensor, every bitx/zipnn record merges
+    in its own device launch, and retrieval still returns the source bytes."""
+    from repro.core import pipeline as pipeline_mod
+    monkeypatch.setattr(pipeline_mod, "_DEVICE_BATCH_MAX_BYTES", 1)
+    root, manifest = corpus_dir
+    s = ZLLMStore(str(tmp_path / "jax"), workers=4, backend="jax")
+    for rid, _ in manifest[:3]:
+        s.ingest_repo(os.path.join(root, rid), rid)
+    for rid, _ in manifest[:3]:
+        orig = open(os.path.join(root, rid, "model.safetensors"), "rb").read()
+        assert s.retrieve_file(rid, "model.safetensors") == orig
+    assert s.summary()["codec_bytes"].get("bitx", 0) > 0
+    s.close()
+
+
 def test_get_backend_resolution():
     assert get_backend("numpy").name == "numpy"
     assert get_backend("jax").name == "jax"
-    # auto on a CPU-only box falls back to numpy (throughput: interpret-mode
-    # kernels are Python emulation); on an accelerator host it picks jax
+    # auto picks jax only on a TPU host; elsewhere the numpy host path
+    # (interpret-mode kernels are Python emulation)
     import jax
-    expected = "numpy" if jax.default_backend() == "cpu" else "jax"
+    expected = "jax" if jax.default_backend() == "tpu" else "numpy"
     assert get_backend("auto").name == expected
     # instances pass through, unknown names fail loudly
     nb = NumpyBackend()
     assert get_backend(nb) is nb
     with pytest.raises(ValueError, match="torch"):
         get_backend("torch")
+
+
+def test_path_counts_same_keys_on_both_backends():
+    """``array_path`` has one shape on every backend: numpy counts every
+    tensor as host path; jax counts device kernels, and host for 8-byte
+    words without x64."""
+    from repro.core.bitx import JaxBackend
+    xs = [_mk(np.uint16, (777,), 41), _mk(np.float64, (33,), 42)]
+    nb, jb = NumpyBackend(), JaxBackend()
+    nb.byte_planes_batch(xs)
+    jb.byte_planes_batch(xs)
+    assert nb.path_counts() == {"device_tensors": 0, "device_bytes": 0,
+                                "host_tensors": 2, "host_bytes": 777 * 2 + 33 * 8}
+    assert jb.path_counts() == {"device_tensors": 1, "device_bytes": 777 * 2,
+                                "host_tensors": 1, "host_bytes": 33 * 8}

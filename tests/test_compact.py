@@ -18,7 +18,7 @@ import tempfile
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as stt
+from hypothesis import given, settings, strategies as stt
 from repro.core.lifecycle import make_vid
 from repro.core.pipeline import COMPACT_KEY, ZLLMStore
 from repro.formats import safetensors as st

@@ -7,7 +7,7 @@ import os
 import ml_dtypes
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as stt
+from hypothesis import given, settings, strategies as stt
 
 from repro.core.bitdistance import (bit_distance_arrays, expected_bit_distance_mc,
                                     shape_signature)

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as stt
+from hypothesis import given, settings, strategies as stt
 
 from repro.kernels import bitx_xor, byte_planes, hamming, ops, ref
 
@@ -81,7 +81,18 @@ def test_byte_planes_roundtrip(dtype):
 # ops.py public API: arbitrary shapes/floats, pallas vs jnp-ref vs numpy
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("shape", [(7,), (33, 5), (2, 3, 129), (1025,)])
+@pytest.mark.parametrize("numel,rows", [(1, 1), (1025, 2), (256 * 1024, 256),
+                                        (256 * 1024 + 1, 512), (66304 * 1024, 66304)])
+def test_packed_rows_pads_to_whole_blocks(numel, rows):
+    """Up to one block the whole array is the block; above it rows pad up to
+    whole fixed blocks, so any bucket length gets a legal TPU block shape."""
+    assert ops.packed_rows(numel) == rows
+    br = ops.block_rows_for(rows)
+    assert rows % br == 0 and br == min(rows, bitx_xor.DEFAULT_BLOCK_ROWS)
+
+
+@pytest.mark.parametrize("shape", [(7,), (33, 5), (2, 3, 129), (1025,),
+                                   (257 * 1024 + 3,)])
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_ops_encode_decode_roundtrip(shape, dtype):
     k1, k2 = jax.random.split(jax.random.PRNGKey(4))

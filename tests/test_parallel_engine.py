@@ -169,6 +169,17 @@ def test_process_entropy_backend_bit_identical(tmp_path):
     s_proc.close()
 
 
+def test_entropy_worker_probe_detects_an_initialised_backend():
+    """The initializer every spawned entropy worker runs raises once a JAX
+    backend is initialised in its process (here: the test process's own)."""
+    import jax
+    from repro.core.pipeline import _entropy_worker_holds_jax, _entropy_worker_init
+    jax.devices()
+    assert _entropy_worker_holds_jax()
+    with pytest.raises(RuntimeError, match="JAX backend"):
+        _entropy_worker_init()
+
+
 def test_pipelined_write_failure_rolls_back_cleanly(tmp_path, monkeypatch):
     """A failed deferred container write must not leave the index pointing
     at a container that never landed: the batch raises, the failed upload's

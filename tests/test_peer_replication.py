@@ -22,7 +22,7 @@ from collections import OrderedDict
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as stt
+from hypothesis import given, settings, strategies as stt
 
 from benchmarks.chaos import ChaosProxy
 from repro.core.pipeline import ZLLMStore

@@ -29,7 +29,7 @@ import pytest
 from benchmarks.corpus import (CorpusSpec, make_base_tensors, make_corpus,
                                make_finetune, make_quantized_int4,
                                make_quantized_int8)
-from repro.core.bitx import JaxBackend, TensorRecord
+from repro.core.bitx import TensorRecord
 from repro.core.codecs import CodecRuntime, EncodeInput, get_codec
 from repro.core.pipeline import ZLLMStore
 from repro.formats import safetensors as st
@@ -226,7 +226,6 @@ def test_store_bitxq_survives_gc_and_compact(tmp_path, qcorpus):
         assert store.retrieve_file(rid, "model.safetensors") == orig
 
 
-@pytest.mark.skipif(not JaxBackend.available(), reason="jax not installed")
 def test_store_bitxq_containers_bit_identical_numpy_vs_jax(tmp_path, qcorpus):
     """The bitxq prediction is pinned to host numpy precisely so the
     container bytes cannot depend on the backend: same corpus, numpy vs
