@@ -40,6 +40,7 @@ from typing import Dict, List, Optional, Protocol, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.codecs import CodecRuntime, EncodeInput, get_codec, raw_or_stored
 
 __all__ = [
@@ -282,107 +283,135 @@ class JaxBackend(_PathCounts):
             groups.setdefault(np.dtype(d).str, []).append(i)
         return groups
 
+    # Each batch call is one ``zllm.array.encode`` or ``zllm.array.decode``
+    # span; a device bucket in it is a ``zllm.array.concat`` (the host
+    # copies into one buffer), a ``zllm.array.device`` (the kernel call
+    # until its result is numpy: host-to-device, the programs,
+    # device-to-host) and a ``zllm.array.slice`` (the per-tensor copies).
     def xor_delta_planes_batch(self, pairs):
         out: List[Optional[List[np.ndarray]]] = [None] * len(pairs)
-        views = []
-        for base, ft in pairs:
-            a = _bit_view_np(np.ascontiguousarray(base)).reshape(-1)
-            b = _bit_view_np(np.ascontiguousarray(ft)).reshape(-1)
-            assert a.shape == b.shape and a.dtype == b.dtype, \
-                (a.shape, b.shape, a.dtype, b.dtype)
-            views.append((a, b))
-        for dstr, idxs in self._buckets([v[0].dtype for v in views]).items():
-            if not self._device_ok(np.dtype(dstr)):
-                self._count("host", [views[i][0].nbytes for i in idxs])
-                for i in idxs:
-                    out[i] = _xor_delta_planes_host(*views[i])
-                continue
-            self._count("device", [views[i][0].nbytes for i in idxs])
-            cat_a = np.concatenate([views[i][0] for i in idxs])
-            cat_b = np.concatenate([views[i][1] for i in idxs])
-            planes = [np.asarray(p) for p in self._ops().bitx_encode_planes(
-                cat_a, cat_b, use_pallas=self.use_pallas)]
-            off = 0
-            for i in idxs:
-                n = views[i][0].size
-                out[i] = [np.ascontiguousarray(p[off:off + n]) for p in planes]
-                off += n
+        with obs.span("zllm.array.encode") as sp:
+            views = []
+            for base, ft in pairs:
+                a = _bit_view_np(np.ascontiguousarray(base)).reshape(-1)
+                b = _bit_view_np(np.ascontiguousarray(ft)).reshape(-1)
+                assert a.shape == b.shape and a.dtype == b.dtype, \
+                    (a.shape, b.shape, a.dtype, b.dtype)
+                views.append((a, b))
+            sp.set(bytes=sum(b.nbytes for _, b in views))
+            for dstr, idxs in self._buckets([v[0].dtype for v in views]).items():
+                if not self._device_ok(np.dtype(dstr)):
+                    self._count("host", [views[i][0].nbytes for i in idxs])
+                    for i in idxs:
+                        out[i] = _xor_delta_planes_host(*views[i])
+                    continue
+                self._count("device", [views[i][0].nbytes for i in idxs])
+                with obs.span("zllm.array.concat"):
+                    cat_a = np.concatenate([views[i][0] for i in idxs])
+                    cat_b = np.concatenate([views[i][1] for i in idxs])
+                with obs.span("zllm.array.device", bytes=cat_b.nbytes):
+                    planes = [np.asarray(p) for p in self._ops().bitx_encode_planes(
+                        cat_a, cat_b, use_pallas=self.use_pallas)]
+                with obs.span("zllm.array.slice"):
+                    off = 0
+                    for i in idxs:
+                        n = views[i][0].size
+                        out[i] = [np.ascontiguousarray(p[off:off + n])
+                                  for p in planes]
+                        off += n
         return out
 
     def byte_planes_batch(self, xs):
         out: List[Optional[List[np.ndarray]]] = [None] * len(xs)
-        views = [_bit_view_np(np.ascontiguousarray(x)).reshape(-1) for x in xs]
-        for dstr, idxs in self._buckets([v.dtype for v in views]).items():
-            if not self._device_ok(np.dtype(dstr)):
-                self._count("host", [views[i].nbytes for i in idxs])
-                for i in idxs:
-                    out[i] = _byte_planes_host(views[i])
-                continue
-            self._count("device", [views[i].nbytes for i in idxs])
-            cat = np.concatenate([views[i] for i in idxs])
-            planes = [np.asarray(p) for p in self._ops().zipnn_split_planes(
-                cat, use_pallas=self.use_pallas)]
-            off = 0
-            for i in idxs:
-                n = views[i].size
-                out[i] = [np.ascontiguousarray(p[off:off + n]) for p in planes]
-                off += n
+        with obs.span("zllm.array.encode") as sp:
+            views = [_bit_view_np(np.ascontiguousarray(x)).reshape(-1) for x in xs]
+            sp.set(bytes=sum(v.nbytes for v in views))
+            for dstr, idxs in self._buckets([v.dtype for v in views]).items():
+                if not self._device_ok(np.dtype(dstr)):
+                    self._count("host", [views[i].nbytes for i in idxs])
+                    for i in idxs:
+                        out[i] = _byte_planes_host(views[i])
+                    continue
+                self._count("device", [views[i].nbytes for i in idxs])
+                with obs.span("zllm.array.concat"):
+                    cat = np.concatenate([views[i] for i in idxs])
+                with obs.span("zllm.array.device", bytes=cat.nbytes):
+                    planes = [np.asarray(p) for p in self._ops().zipnn_split_planes(
+                        cat, use_pallas=self.use_pallas)]
+                with obs.span("zllm.array.slice"):
+                    off = 0
+                    for i in idxs:
+                        n = views[i].size
+                        out[i] = [np.ascontiguousarray(p[off:off + n])
+                                  for p in planes]
+                        off += n
         return out
 
     def merge_planes_xor_batch(self, items):
         out: List[Optional[np.ndarray]] = [None] * len(items)
-        views = [_bit_view_np(np.ascontiguousarray(base)) for _, base in items]
-        for dstr, idxs in self._buckets([v.dtype for v in views]).items():
-            if not self._device_ok(np.dtype(dstr)):
-                self._count("host", [views[i].nbytes for i in idxs])
-                for i in idxs:
-                    out[i] = _merge_planes_xor_host(items[i][0], views[i])
-                continue
-            self._count("device", [views[i].nbytes for i in idxs])
-            nb = np.dtype(dstr).itemsize
-            cat_base = np.concatenate([views[i].reshape(-1) for i in idxs])
-            cat_planes = [
-                np.concatenate([np.ascontiguousarray(np.asarray(items[i][0][pi]))
-                                for i in idxs])
-                for pi in range(nb)]
-            merged = np.asarray(self._ops().bitx_decode_planes(
-                cat_planes, cat_base, use_pallas=self.use_pallas))
-            off = 0
-            for i in idxs:
-                n = views[i].size
-                out[i] = np.ascontiguousarray(
-                    merged[off:off + n]).reshape(views[i].shape)
-                off += n
+        with obs.span("zllm.array.decode") as sp:
+            views = [_bit_view_np(np.ascontiguousarray(base)) for _, base in items]
+            sp.set(bytes=sum(v.nbytes for v in views))
+            for dstr, idxs in self._buckets([v.dtype for v in views]).items():
+                if not self._device_ok(np.dtype(dstr)):
+                    self._count("host", [views[i].nbytes for i in idxs])
+                    for i in idxs:
+                        out[i] = _merge_planes_xor_host(items[i][0], views[i])
+                    continue
+                self._count("device", [views[i].nbytes for i in idxs])
+                nb = np.dtype(dstr).itemsize
+                with obs.span("zllm.array.concat"):
+                    cat_base = np.concatenate([views[i].reshape(-1) for i in idxs])
+                    cat_planes = [
+                        np.concatenate([np.ascontiguousarray(np.asarray(items[i][0][pi]))
+                                        for i in idxs])
+                        for pi in range(nb)]
+                with obs.span("zllm.array.device", bytes=cat_base.nbytes):
+                    merged = np.asarray(self._ops().bitx_decode_planes(
+                        cat_planes, cat_base, use_pallas=self.use_pallas))
+                with obs.span("zllm.array.slice"):
+                    off = 0
+                    for i in idxs:
+                        n = views[i].size
+                        out[i] = np.ascontiguousarray(
+                            merged[off:off + n]).reshape(views[i].shape)
+                        off += n
         return out
 
     def merge_planes_batch(self, items):
         out: List[Optional[np.ndarray]] = [None] * len(items)
         dtypes = [np.dtype(d) for _, d, _ in items]
-        for dstr, idxs in self._buckets(dtypes).items():
-            dtype_np = np.dtype(dstr)
-            nb = dtype_np.itemsize
-            sizes = [nb * int(np.prod(items[i][2])) for i in idxs]
-            if not self._device_ok(dtype_np):
-                self._count("host", sizes)
-                for i in idxs:
-                    out[i] = _merge_planes_host(*items[i])
-                continue
-            self._count("device", sizes)
-            uview = np.dtype(f"<u{nb}")
-            cat_planes = [
-                np.concatenate([np.ascontiguousarray(np.asarray(items[i][0][pi]))
-                                for i in idxs])
-                for pi in range(nb)]
-            total = int(cat_planes[0].size)
-            merged = np.asarray(self._ops().zipnn_merge_planes(
-                cat_planes, uview, (total,), use_pallas=self.use_pallas))
-            off = 0
-            for i in idxs:
-                shape = items[i][2]
-                n = int(np.prod(shape)) if len(shape) else 1
-                out[i] = np.ascontiguousarray(
-                    merged[off:off + n]).view(dtype_np.str).reshape(shape)
-                off += n
+        with obs.span("zllm.array.decode") as sp:
+            sp.set(bytes=sum(d.itemsize * int(np.prod(shape))
+                             for d, (_, _, shape) in zip(dtypes, items)))
+            for dstr, idxs in self._buckets(dtypes).items():
+                dtype_np = np.dtype(dstr)
+                nb = dtype_np.itemsize
+                sizes = [nb * int(np.prod(items[i][2])) for i in idxs]
+                if not self._device_ok(dtype_np):
+                    self._count("host", sizes)
+                    for i in idxs:
+                        out[i] = _merge_planes_host(*items[i])
+                    continue
+                self._count("device", sizes)
+                uview = np.dtype(f"<u{nb}")
+                with obs.span("zllm.array.concat"):
+                    cat_planes = [
+                        np.concatenate([np.ascontiguousarray(np.asarray(items[i][0][pi]))
+                                        for i in idxs])
+                        for pi in range(nb)]
+                total = int(cat_planes[0].size)
+                with obs.span("zllm.array.device", bytes=total * nb):
+                    merged = np.asarray(self._ops().zipnn_merge_planes(
+                        cat_planes, uview, (total,), use_pallas=self.use_pallas))
+                with obs.span("zllm.array.slice"):
+                    off = 0
+                    for i in idxs:
+                        shape = items[i][2]
+                        n = int(np.prod(shape)) if len(shape) else 1
+                        out[i] = np.ascontiguousarray(
+                            merged[off:off + n]).view(dtype_np.str).reshape(shape)
+                        off += n
         return out
 
 

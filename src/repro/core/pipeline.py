@@ -159,6 +159,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tupl
 import numpy as np
 import zstandard as zstd
 
+from repro import obs
 from repro.core.bitx import (TMP_SUFFIX, BitXReader, BitXWriter, get_backend)
 from repro.core.clustering import FamilyRegistry
 from repro.core.codecs import CodecRuntime, EncodeInput, get_codec, raw_or_stored
@@ -297,7 +298,6 @@ class IngestResult:
     n_bitxq: int = 0
     n_zipnn: int = 0
     n_raw: int = 0
-    ingest_seconds: float = 0.0
 
     @property
     def reduction(self) -> float:
@@ -322,6 +322,17 @@ class IngestJob:
     started_at: float = 0.0
     finished_at: float = 0.0
 
+    @property
+    def key(self) -> str:
+        """What the job works on, as its spans name it: ``<repo>/<file>``
+        of each upload, the repo of a repo job, a repair's note."""
+        if self.kind == "files":
+            return ",".join(f"{s[1]}/{s[2]}" for s in self.specs)
+        if self.kind == "repo":
+            return ",".join(s[1] or os.path.basename(os.path.normpath(s[0]))
+                            for s in self.specs)
+        return self.specs[0][1]
+
     def to_json(self) -> Dict:
         return {"job_id": self.job_id, "kind": self.kind, "state": self.state,
                 "n_uploads": len(self.specs), "error": self.error,
@@ -338,7 +349,6 @@ class StoreStats:
     n_files: int = 0
     n_file_dedup: int = 0
     n_near_dup: int = 0
-    ingest_seconds: float = 0.0
     # lifecycle accounting: bytes currently on disk in live container
     # versions vs bytes reclaimed by gc() over the store's lifetime
     live_bytes: int = 0
@@ -359,10 +369,6 @@ class StoreStats:
     @property
     def reduction_ratio(self) -> float:
         return 1.0 - self.stored_bytes / self.raw_bytes if self.raw_bytes else 0.0
-
-    @property
-    def ingest_throughput_mbps(self) -> float:
-        return (self.raw_bytes / 2**20) / self.ingest_seconds if self.ingest_seconds else 0.0
 
 
 class _ReadGate:
@@ -568,7 +574,7 @@ class _PreparedUpload:
     upload N encodes."""
 
     __slots__ = ("path", "repo_id", "filename", "key", "declared_base",
-                 "raw_size", "fhash", "sf", "header_blob", "t0", "error")
+                 "raw_size", "fhash", "sf", "header_blob", "error")
 
     def __init__(self, path: str, repo_id: str, filename: str,
                  declared_base: Optional[str]):
@@ -577,7 +583,6 @@ class _PreparedUpload:
         self.filename = filename
         self.key = f"{repo_id}/{filename}"
         self.declared_base = declared_base
-        self.t0 = time.perf_counter()
         self.raw_size = 0
         self.fhash = ""
         self.sf: Optional[SafetensorsFile] = None
@@ -586,7 +591,8 @@ class _PreparedUpload:
 
     def close(self) -> None:
         if self.sf is not None:
-            self.sf.close()
+            with obs.span("zllm.source.close", key=self.key):  # the unmap
+                self.sf.close()
             self.sf = None
 
 
@@ -875,7 +881,6 @@ class ZLLMStore:
         # container must be undone too (their bytes exist nowhere else)
         ref_entries: List[Tuple[str, IngestResult]] = []
         spec_iter = iter(specs)
-        batch_t0 = time.perf_counter()
 
         def top_up():
             while len(ahead) <= depth:
@@ -890,7 +895,8 @@ class ZLLMStore:
                 while ahead:
                     pf = ahead.popleft().result()
                     top_up()  # keep stage A ``depth`` uploads ahead
-                    res, pw = self._ingest_decide(pf)
+                    with obs.span("zllm.decide", key=pf.key, bytes=pf.raw_size):
+                        res, pw = self._ingest_decide(pf)
                     out.append(res)
                     self.results.append(res)
                     if pw is None:
@@ -909,7 +915,8 @@ class ZLLMStore:
             else:
                 for spec in specs:
                     pf = self._prepare_upload(*spec)
-                    res, pw = self._ingest_decide(pf)
+                    with obs.span("zllm.decide", key=pf.key, bytes=pf.raw_size):
+                        res, pw = self._ingest_decide(pf)
                     out.append(res)
                     self.results.append(res)
                     if pw is None:
@@ -941,9 +948,6 @@ class ZLLMStore:
                 except BaseException:
                     pass
             raise
-        finally:
-            # batch wall-clock, not the sum of (overlapping) per-file times
-            self.stats.ingest_seconds += time.perf_counter() - batch_t0
         return out
 
     def _prepare_upload(self, path: str, repo_id: str, filename: str,
@@ -952,7 +956,8 @@ class ZLLMStore:
         pf = _PreparedUpload(path, repo_id, filename, declared_base)
         try:
             pf.raw_size = os.path.getsize(path)
-            pf.fhash, _ = sha256_file(path)
+            with obs.span("zllm.hash.file", key=pf.key, bytes=pf.raw_size):
+                pf.fhash, _ = sha256_file(path)
             pf.sf = SafetensorsFile(path)
             pf.sf.advise("sequential")  # ingest walks tensors in order
             pf.header_blob = self._read_header_blob(path)
@@ -975,8 +980,7 @@ class ZLLMStore:
         if not is_new_file and ref is not None and ref in self.file_index:
             pf.close()
             res = IngestResult(pf.repo_id, pf.filename, raw_size, 0,
-                               file_dedup_hit=True,
-                               ingest_seconds=time.perf_counter() - pf.t0)
+                               file_dedup_hit=True)
             if ref != key:
                 self._set_index_entry(key, self._pinned_ref(ref, fhash, raw_size))
             # ref == key: identical content re-ingested under its own key —
@@ -992,7 +996,7 @@ class ZLLMStore:
         gen: Optional[int] = None
         pw: Optional[_PendingWrite] = None
         try:
-            get_hash = self._hash_stage(sf)
+            get_hash = self._hash_stage(sf, key)
             # near-identical re-ingest (same tensors, different header
             # metadata): store the header + a pinned reference, no container.
             # The probe awaits only the first hash unless a candidate matches,
@@ -1000,7 +1004,7 @@ class ZLLMStore:
             near = self._near_dup_probe(sf, get_hash)
             if near is not None:
                 res = self._ingest_near_dup(res, sf, key, fhash, raw_size,
-                                            pf.header_blob, near, pf.t0)
+                                            pf.header_blob, near)
                 pf.close()  # a full probe match awaited every tensor hash
                 return res, None
             # ③a/③b family resolution (before encoding, so BitX knows its base)
@@ -1074,9 +1078,12 @@ class ZLLMStore:
         write the container, release the publish epoch. Runs inline (serial)
         or on the writer thread (pipelined); the bytes are identical."""
         try:
-            self._merge_plan(pw.writer, pw.plan)
-            os.makedirs(os.path.dirname(pw.cpath), exist_ok=True)
-            stored = pw.writer.write(pw.cpath)
+            with obs.span("zllm.container.merge", key=pw.key):
+                self._merge_plan(pw.writer, pw.plan)
+            with obs.span("zllm.container.write", key=pw.key) as sp:
+                os.makedirs(os.path.dirname(pw.cpath), exist_ok=True)
+                stored = pw.writer.write(pw.cpath)
+                sp.set(bytes=stored)
         except BaseException:
             # drain the remaining encode futures before the finally closes
             # the source mmap (mirrors _plan_tensors' stage-B drain)
@@ -1104,7 +1111,6 @@ class ZLLMStore:
             self._rollback_failed_write(pw)
             raise
         pw.res.stored_bytes = stored
-        pw.res.ingest_seconds = time.perf_counter() - pw.pf.t0
         self.lifecycle.set_nbytes(pw.key, pw.gen, stored)
         for r in pw.writer.records:
             self.stats.codec_bytes[r.codec] = (
@@ -1268,7 +1274,7 @@ class ZLLMStore:
 
     def _ingest_near_dup(self, res: IngestResult, sf: SafetensorsFile, key: str,
                          fhash: str, raw_size: int, header_blob: bytes,
-                         target: Tuple[str, int], t0: float) -> IngestResult:
+                         target: Tuple[str, int]) -> IngestResult:
         """Satellite fix: a file whose tensors all hash-match one existing
         container version in order needs no container of its own — only its
         header blob differs, so store that plus a pinned reference."""
@@ -1284,7 +1290,6 @@ class ZLLMStore:
                                     "file_hash": fhash, "raw_size": raw_size,
                                     "n_tensors": n, "header_blob_z": blob_z})
         res.stored_bytes = len(blob_z)
-        res.ingest_seconds = time.perf_counter() - t0
         self.stats.n_near_dup += 1
         return res
 
@@ -1312,14 +1317,17 @@ class ZLLMStore:
             return None
         return None
 
-    def _hash_stage(self, sf: SafetensorsFile) -> Callable[[int], str]:
+    def _hash_stage(self, sf: SafetensorsFile, key: str) -> Callable[[int], str]:
         """Stage 1: submit big-tensor sha256 jobs to the pool and return a
         memoized per-index getter. Callers resolve hashes lazily, so encode
         submission overlaps the remaining hash work exactly as in PR 1."""
         pool = self._executor()
-        hash_one = self.tensor_dedup.hash_tensor
         infos = sf.infos
-        futs = ([pool.submit(hash_one, sf.tensor_bytes(ti.name))
+
+        def hash_one(ti) -> str:
+            with obs.span("zllm.hash.tensor", key=key, bytes=ti.nbytes):
+                return self.tensor_dedup.hash_tensor(sf.tensor_bytes(ti.name))
+        futs = ([pool.submit(hash_one, ti)
                  if ti.nbytes >= _PARALLEL_MIN_BYTES else None for ti in infos]
                 if pool is not None else None)
         cache: Dict[int, str] = {}
@@ -1328,7 +1336,7 @@ class ZLLMStore:
             h = cache.get(i)
             if h is None:
                 h = (futs[i].result() if futs is not None and futs[i] is not None
-                     else hash_one(sf.tensor_bytes(infos[i].name)))
+                     else hash_one(infos[i]))
                 cache[i] = h
             return h
         return get_hash
@@ -1424,11 +1432,11 @@ class ZLLMStore:
                     batch.append((payload, kind, ti, base_loader))
                     batch_bytes += ti.nbytes
                     if batch_bytes >= _DEVICE_BATCH_MAX_BYTES:
-                        self._flush_device_batch(sf, batch, pool, epool)
+                        self._flush_device_batch(sf, key, batch, pool, epool)
                         batch, batch_bytes = [], 0
                 else:
-                    job = self._encode_job(self._codec_runtime, kind, sf, ti,
-                                           base_loader, epool, base_dtype)
+                    job = self._encode_job(self._codec_runtime, kind, sf, key,
+                                           ti, base_loader, epool, base_dtype)
                     payload = (pool.submit(job)
                                if pool is not None and ti.nbytes >= _PARALLEL_MIN_BYTES
                                else job())
@@ -1439,9 +1447,10 @@ class ZLLMStore:
             # Record index == tensor index (dedup entries are records too).
             self.tensor_locations.setdefault(thash, (key, gen, i))
         if batch:
-            self._flush_device_batch(sf, batch, pool, epool)
+            self._flush_device_batch(sf, key, batch, pool, epool)
 
-    def _flush_device_batch(self, sf, batch: List[Tuple[Future, str, Any, Any]],
+    def _flush_device_batch(self, sf, key: str,
+                            batch: List[Tuple[Future, str, Any, Any]],
                             pool, epool) -> None:
         """Run the array stage of the accumulated bitx/zipnn tensors in
         dtype-bucketed fused kernel launches (one per bit-width bucket), then
@@ -1474,7 +1483,7 @@ class ZLLMStore:
         # entropy stage: planes are private copies (the kernel outputs), so
         # these jobs never touch the source mmap and may outlive the plan
         for (fut, kind, ti, _), arr, planes in zip(batch, arrs, planes_of):
-            job = self._entropy_job(kind, planes, int(arr.nbytes), epool)
+            job = self._entropy_job(kind, key, planes, int(arr.nbytes), epool)
             if pool is not None and ti.nbytes >= _PARALLEL_MIN_BYTES:
                 self._chain_future(pool.submit(job), fut)
             else:
@@ -1486,15 +1495,19 @@ class ZLLMStore:
                 if not fut.cancelled():
                     fut.set_result(result)
 
-    def _entropy_job(self, kind: str, planes, raw_size: int,
+    def _entropy_job(self, kind: str, key: str, planes, raw_size: int,
                      epool) -> Callable[[], Tuple[str, List[bytes], int]]:
         runtime = self._codec_runtime
         def entropy() -> Tuple[str, List[bytes], int]:
-            if epool is not None:
-                return kind, self._entropy_frames(
-                    epool, [p.tobytes() for p in planes]), raw_size
-            return get_codec(kind).encode(
-                runtime, EncodeInput(planes=planes, raw_size=raw_size))
+            with obs.span("zllm.entropy", key=key, bytes=raw_size) as sp:
+                if epool is not None:
+                    out = kind, self._entropy_frames(
+                        epool, [p.tobytes() for p in planes]), raw_size
+                else:
+                    out = get_codec(kind).encode(
+                        runtime, EncodeInput(planes=planes, raw_size=raw_size))
+                sp.set(out=sum(len(f) for f in out[1]))
+            return out
         return entropy
 
     @staticmethod
@@ -1542,7 +1555,7 @@ class ZLLMStore:
                                        thash, frames, raw, extras)
 
     def _encode_job(self, runtime: CodecRuntime, kind: str, sf: SafetensorsFile,
-                    ti, base_loader,
+                    key: str, ti, base_loader,
                     epool, base_dtype: Optional[str] = None
                     ) -> Callable[[], Tuple[str, List[bytes], int]]:
         """Closure encoding one tensor via the codec registry; safe to run on
@@ -1558,8 +1571,15 @@ class ZLLMStore:
         in-thread via the registry, even under the entropy pool: its
         lane-vs-standalone decision needs both the residual frames and the
         standalone frame, and the frames are identical executor-independent
-        anyway."""
+        anyway. The whole job is one ``zllm.entropy`` span: on this path
+        the array stage runs inside it."""
         def encode() -> Tuple[str, List[bytes], int]:
+            with obs.span("zllm.entropy", key=key, bytes=ti.nbytes) as sp:
+                out = encode_one()
+                sp.set(out=sum(len(f) for f in out[1]))
+            return out
+
+        def encode_one() -> Tuple[str, List[bytes], int]:
             raw = sf.tensor_bytes(ti.name)
             if kind == "raw":
                 data = bytes(raw)
@@ -1709,10 +1729,7 @@ class ZLLMStore:
     def _account_stats(self, res: IngestResult):
         """Fold a finished ingest result into the store totals. Results are
         appended to ``self.results`` at decision time (submission order);
-        these sums commute, so deferred-write commits may fold out of order.
-        ``stats.ingest_seconds`` is NOT summed here: per-file times overlap
-        under the cross-file pipeline, so ``ingest_many`` accounts batch
-        wall-clock instead (keeping ``ingest_throughput_mbps`` honest)."""
+        these sums commute, so deferred-write commits may fold out of order."""
         self.stats.raw_bytes += res.raw_bytes
         self.stats.stored_bytes += res.stored_bytes
         self.stats.n_files += 1
@@ -1802,66 +1819,72 @@ class ZLLMStore:
             with self._job_cv:
                 job.state = "running"
                 job.started_at = time.time()
-            try:
-                if job.kind == "repair":
-                    thunk, note = job.specs[0]
-                    out = thunk() or {}
-                    out.setdefault("note", note)
+            # the wait began on the enqueuing thread: a counter, and a stat
+            # of the job's span
+            queued = max(0.0, job.started_at - job.enqueued_at)
+            obs.add("zllm.job.queued", queued)
+            with obs.span("zllm.job", key=job.key, queued_s=round(queued, 6)):
+                try:
+                    if job.kind == "repair":
+                        thunk, note = job.specs[0]
+                        out = thunk() or {}
+                        out.setdefault("note", note)
+                        with self._admin_lock:
+                            self.save_index()
+                        with self._job_cv:
+                            job.results = [out]
+                            job.state = "done"
+                            job.finished_at = time.time()
+                            self._job_cv.notify_all()
+                        continue
+                    if job.kind == "repo":
+                        results = self.ingest_repos(job.specs)
+                    else:
+                        results = self.ingest_many(job.specs)
+                    # adopt/cleanup spool sources BEFORE persisting: the index
+                    # snapshot must record the post-adoption base paths, never
+                    # a spool path about to be renamed away
+                    self._cleanup_job_sources(job)
+                    # remote writes are durable once acknowledged as done; the
+                    # admin lock keeps the snapshot consistent against a
+                    # concurrent delete/gc on another thread
                     with self._admin_lock:
                         self.save_index()
+                except Exception as e:
+                    # a poisoned batch may still have committed earlier uploads
+                    # (possibly a base) — adopt-or-delete runs here too
+                    self._cleanup_job_sources(job)
                     with self._job_cv:
-                        job.results = [out]
+                        job.state = "failed"
+                        job.error = f"{type(e).__name__}: {e}"
+                        job.finished_at = time.time()
+                        self._job_cv.notify_all()
+                else:
+                    rows = [{"repo_id": r.repo_id, "filename": r.filename,
+                             "raw_bytes": r.raw_bytes, "stored_bytes": r.stored_bytes,
+                             "reduction": round(r.reduction, 4),
+                             "base_id": r.base_id, "base_source": r.base_source,
+                             "n_tensors": r.n_tensors, "n_dedup": r.n_dedup,
+                             "n_bitx": r.n_bitx, "n_bitxq": r.n_bitxq,
+                             "file_dedup_hit": r.file_dedup_hit,
+                             "near_dup_hit": r.near_dup_hit} for r in results]
+                    with self._job_cv:
+                        job.results = rows
                         job.state = "done"
                         job.finished_at = time.time()
                         self._job_cv.notify_all()
-                    continue
-                if job.kind == "repo":
-                    results = self.ingest_repos(job.specs)
-                else:
-                    results = self.ingest_many(job.specs)
-                # adopt/cleanup spool sources BEFORE persisting: the index
-                # snapshot must record the post-adoption base paths, never
-                # a spool path about to be renamed away
-                self._cleanup_job_sources(job)
-                # remote writes are durable once acknowledged as done; the
-                # admin lock keeps the snapshot consistent against a
-                # concurrent delete/gc on another thread
-                with self._admin_lock:
-                    self.save_index()
-            except Exception as e:
-                # a poisoned batch may still have committed earlier uploads
-                # (possibly a base) — adopt-or-delete runs here too
-                self._cleanup_job_sources(job)
-                with self._job_cv:
-                    job.state = "failed"
-                    job.error = f"{type(e).__name__}: {e}"
-                    job.finished_at = time.time()
-                    self._job_cv.notify_all()
-            else:
-                rows = [{"repo_id": r.repo_id, "filename": r.filename,
-                         "raw_bytes": r.raw_bytes, "stored_bytes": r.stored_bytes,
-                         "reduction": round(r.reduction, 4),
-                         "base_id": r.base_id, "base_source": r.base_source,
-                         "n_tensors": r.n_tensors, "n_dedup": r.n_dedup,
-                         "n_bitx": r.n_bitx, "n_bitxq": r.n_bitxq,
-                         "file_dedup_hit": r.file_dedup_hit,
-                         "near_dup_hit": r.near_dup_hit} for r in results]
-                with self._job_cv:
-                    job.results = rows
-                    job.state = "done"
-                    job.finished_at = time.time()
-                    self._job_cv.notify_all()
 
     def _cleanup_job_sources(self, job: "IngestJob") -> None:
         """Adopt-or-delete a finished job's spooled sources (idempotent)."""
         if not (job.cleanup and job.kind == "files"):
             return
-        for path, *_ in job.specs:
-            try:
-                if os.path.exists(path) and not self._adopt_spooled_source(path):
-                    os.remove(path)
-            except OSError:
-                pass
+        with obs.span("zllm.spool.cleanup"):
+            for path, *_ in job.specs:
+                try:
+                    if os.path.exists(path) and not self._adopt_spooled_source(path):
+                        os.remove(path)
+                except OSError:
+                    pass
 
     def _adopt_spooled_source(self, path: str) -> bool:
         """A spooled upload that registered as a family BASE must outlive
@@ -3495,39 +3518,41 @@ class ZLLMStore:
     # ingesting or serve retrievals immediately.
     # ------------------------------------------------------------------
     def save_index(self) -> str:
-        def sig_key(sig):
-            return json.dumps([[d, list(sh)] for d, sh in sig])
-        idx = {
-            "format": INDEX_FORMAT,
-            "stats": vars(self.stats),
-            "gc_cursor": self._gc_cursor,  # v3: resumable incremental-GC sweep
-            "lifecycle": self.lifecycle.to_json(),
-            "file_index": self.file_index,
-            "file_hash_to_key": self.file_hash_to_key,
-            "tensor_locations": {k: list(v) for k, v in self.tensor_locations.items()},
-            "base_paths": self.base_paths,
-            "base_key_of": self.base_key_of,
-            "metadata_base": self.metadata_base,
-            "file_dedup_index": self.file_dedup.index,
-            "file_dedup_stats": self._stats_to_json(self.file_dedup.stats),
-            "tensor_dedup": {
-                "index": self.tensor_dedup.index,
-                "stats": self._stats_to_json(self.tensor_dedup.stats),
-            },
-            "base_maps": {
-                bid: {"path": bm.path,
-                      "entries": [[n, d, list(s), h] for n, d, s, h in bm.entries]}
-                for bid, bm in self._base_maps.items()
-            },
-            "families": {sig_key(sig): v for sig, v in self.families.by_sig.items()},
-            "n_file_dedup": self.stats.n_file_dedup,
-        }
-        path = os.path.join(self.root, "index.json")
-        tmp = path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(idx, f)
-        os.replace(tmp, path)
-        return path
+        with obs.span("zllm.index.save") as sp:
+            def sig_key(sig):
+                return json.dumps([[d, list(sh)] for d, sh in sig])
+            idx = {
+                "format": INDEX_FORMAT,
+                "stats": vars(self.stats),
+                "gc_cursor": self._gc_cursor,  # v3: resumable incremental-GC sweep
+                "lifecycle": self.lifecycle.to_json(),
+                "file_index": self.file_index,
+                "file_hash_to_key": self.file_hash_to_key,
+                "tensor_locations": {k: list(v) for k, v in self.tensor_locations.items()},
+                "base_paths": self.base_paths,
+                "base_key_of": self.base_key_of,
+                "metadata_base": self.metadata_base,
+                "file_dedup_index": self.file_dedup.index,
+                "file_dedup_stats": self._stats_to_json(self.file_dedup.stats),
+                "tensor_dedup": {
+                    "index": self.tensor_dedup.index,
+                    "stats": self._stats_to_json(self.tensor_dedup.stats),
+                },
+                "base_maps": {
+                    bid: {"path": bm.path,
+                          "entries": [[n, d, list(s), h] for n, d, s, h in bm.entries]}
+                    for bid, bm in self._base_maps.items()
+                },
+                "families": {sig_key(sig): v for sig, v in self.families.by_sig.items()},
+                "n_file_dedup": self.stats.n_file_dedup,
+            }
+            path = os.path.join(self.root, "index.json")
+            tmp = path + ".tmp"
+            with open(tmp, "w") as f:
+                json.dump(idx, f)
+                sp.set(bytes=f.tell())
+            os.replace(tmp, path)
+            return path
 
     @staticmethod
     def _stats_to_json(stats) -> Dict:
@@ -3549,8 +3574,10 @@ class ZLLMStore:
             return False
         idx = json.load(open(path))
         fmt = int(idx.get("format", 1))
+        known = StoreStats.__dataclass_fields__
         for k, v in idx["stats"].items():
-            setattr(self.stats, k, v)
+            if k in known:  # older indexes also hold ``ingest_seconds``
+                setattr(self.stats, k, v)
         self.file_index = idx["file_index"]
         self.file_hash_to_key = idx["file_hash_to_key"]
         self._rebuild_file_hash_map()
@@ -3659,5 +3686,4 @@ class ZLLMStore:
             "pipeline_depth": self.pipeline_depth,
             "entropy_procs": self.entropy_procs,
             "read_gen": self.read_gen,
-            "ingest_throughput_MBps": round(self.stats.ingest_throughput_mbps, 1),
         }

@@ -95,6 +95,7 @@ def xor_split_2d(
     spec = _row_blockspec(block_rows, cols)
     out = pl.pallas_call(
         _xor_split_kernel,
+        name="xor_split_2d",
         out_shape=[jax.ShapeDtypeStruct(base.shape, jnp.uint8) for _ in range(nb)],
         in_specs=[spec, spec],
         out_specs=[spec] * nb,
@@ -122,6 +123,7 @@ def merge_xor_2d(
     spec = _row_blockspec(block_rows, cols)
     return pl.pallas_call(
         _merge_xor_kernel,
+        name="merge_xor_2d",
         out_shape=jax.ShapeDtypeStruct(base.shape, base.dtype),
         in_specs=[spec] * (1 + nb),
         out_specs=spec,

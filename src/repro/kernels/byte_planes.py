@@ -50,6 +50,7 @@ def split_2d(
     spec = pl.BlockSpec((block_rows, cols), lambda i: (i, 0))
     out = pl.pallas_call(
         _split_kernel,
+        name="byte_split_2d",
         out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.uint8) for _ in range(nb)],
         in_specs=[spec],
         out_specs=[spec] * nb,
@@ -78,6 +79,7 @@ def merge_2d(
     spec = pl.BlockSpec((block_rows, cols), lambda i: (i, 0))
     return pl.pallas_call(
         _merge_kernel,
+        name="byte_merge_2d",
         out_shape=jax.ShapeDtypeStruct((rows, cols), dtype),
         in_specs=[spec] * nb,
         out_specs=spec,

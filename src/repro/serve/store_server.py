@@ -77,6 +77,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Tuple
 from urllib.parse import parse_qs, unquote, urlsplit
 
+from repro import obs
 from repro.core.bitx import TMP_SUFFIX
 from repro.core.lifecycle import make_vid
 from repro.core.pipeline import ZLLMStore, _LRUCache
@@ -986,7 +987,8 @@ class StoreServer:
         received = 0
         loop = asyncio.get_running_loop()
         try:
-            with os.fdopen(fd, "wb") as f:
+            with obs.span("zllm.http.receive", key=f"{repo_id}/{filename}",
+                          bytes=length), os.fdopen(fd, "wb") as f:
                 while received < length:
                     chunk = await asyncio.wait_for(
                         req.reader.read(min(_UPLOAD_CHUNK, length - received)),
@@ -1118,6 +1120,8 @@ class StoreServer:
                 "roots": {name: e.stats() for name, e in self.engines.items()},
             }
         server["http"] = dict(self.http)
+        # stage counters of this process's host path (repro.obs)
+        server["stages"] = obs.stages()
         await self._respond(writer, 200, {"server": server,
                                           "store": store_stats},
                             keep=req.keep)

@@ -80,3 +80,20 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, dtype, numel):
     compiled = _lower(kernel, words, planes, jnp.dtype(dtype).itemsize,
                       block_rows).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the name each kernel's custom call carries in the device trace, whatever
+# the Python function around it is called
+KERNEL_NAMES = {"xor_split_2d": "xor_split_2d", "merge_xor_2d": "merge_xor_2d",
+                "split_2d": "byte_split_2d", "merge_2d": "byte_merge_2d"}
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_kernel_custom_call_is_named(one_chip, kernel):
+    rows = ops.packed_rows(1 << 18)
+    words = jax.ShapeDtypeStruct((rows, ops.LANES), jnp.uint16, sharding=one_chip)
+    planes = jax.ShapeDtypeStruct((rows, ops.LANES), jnp.uint8, sharding=one_chip)
+    text = _lower(kernel, words, planes, 2, ops.block_rows_for(rows)).compile().as_text()
+    calls = [line.split(" = ")[0].split()[-1] for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert calls and all(c.startswith(f"%{KERNEL_NAMES[kernel]}.") for c in calls), calls
