@@ -28,6 +28,7 @@ from the CAS pool (§4.4.4).
 
 from __future__ import annotations
 
+import functools
 import io
 import json
 import mmap
@@ -35,8 +36,9 @@ import os
 import struct
 import threading
 import warnings
+from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Protocol, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Protocol, Sequence, Tuple
 
 import numpy as np
 
@@ -71,6 +73,20 @@ ENTROPY_BACKEND = "zstd"
 # store's fsck orphan scan recognizes the suffix and deletes them under
 # repair (they are never referenced by the version graph).
 TMP_SUFFIX = ".part"
+
+# Device-resident base bit views (``JaxBackend``): the share of the device's
+# reported memory limit they may hold, which leaves the rest to the largest
+# flush, and the budget where the device reports no limit (the CPU backend).
+_RESIDENT_SHARE = 0.5
+_RESIDENT_FALLBACK_BYTES = 1 << 30
+
+
+@functools.lru_cache(maxsize=None)
+def _resident_budget_bytes() -> int:
+    """Bytes of base bit views ``JaxBackend`` keeps on the default device."""
+    import jax
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    return int(limit * _RESIDENT_SHARE) if limit else _RESIDENT_FALLBACK_BYTES
 
 
 def _bit_view_np(arr: np.ndarray) -> np.ndarray:
@@ -148,6 +164,12 @@ class ArrayBackend(Protocol):
     same-width tensors and run one fused kernel launch per bucket. The
     transforms are elementwise in the bit view, so batching can never change
     the emitted bytes.
+
+    An item of ``xor_delta_planes_batch`` is ``(base, ft)`` or
+    ``(base_loader, ft, base_hash, family)``: a keyed item names its base by
+    content hash, and the base model it belongs to, and loads it only if
+    the backend does not hold it already (``release_resident`` drops what
+    it holds).
     """
 
     name: str
@@ -157,23 +179,33 @@ class ArrayBackend(Protocol):
     def byte_planes(self, x: np.ndarray) -> List[np.ndarray]: ...
     def merge_planes_xor(self, planes: Sequence[np.ndarray], base: np.ndarray) -> np.ndarray: ...
     def merge_planes(self, planes: Sequence[np.ndarray], dtype_np, shape) -> np.ndarray: ...
-    def xor_delta_planes_batch(self, pairs: Sequence[Tuple[np.ndarray, np.ndarray]]) -> List[List[np.ndarray]]: ...
+    def xor_delta_planes_batch(self, pairs: Sequence[Tuple]) -> List[List[np.ndarray]]: ...
     def byte_planes_batch(self, xs: Sequence[np.ndarray]) -> List[List[np.ndarray]]: ...
     def merge_planes_xor_batch(self, items: Sequence[Tuple[Sequence[np.ndarray], np.ndarray]]) -> List[np.ndarray]: ...
     def merge_planes_batch(self, items: Sequence[Tuple[Sequence[np.ndarray], np.dtype, Tuple[int, ...]]]) -> List[np.ndarray]: ...
     def path_counts(self) -> Dict[str, int]: ...
+    def release_resident(self, hashes: Iterable[str]) -> None: ...
+
+
+def _load_base(base) -> np.ndarray:
+    """A batch item's base: the array itself, or what its loader returns."""
+    return base() if callable(base) else base
 
 
 class _PathCounts:
     """Tensors and bytes each backend sent through the device kernels and
     through the host path, since it was created (process-wide:
-    ``get_backend`` shares one instance per spec). Both backends report the
-    same four keys."""
+    ``get_backend`` shares one instance per spec), and the device-resident
+    bases: base bytes served from the device (``resident_hit_bytes``) and
+    put there (``resident_miss_bytes``), bytes held now, entries evicted.
+    Both backends report the same keys; the host path holds nothing."""
 
     def __init__(self):
         self._counts_lock = threading.Lock()
         self._counts = {"device_tensors": 0, "device_bytes": 0,
-                        "host_tensors": 0, "host_bytes": 0}
+                        "host_tensors": 0, "host_bytes": 0,
+                        "resident_hit_bytes": 0, "resident_miss_bytes": 0,
+                        "resident_bytes": 0, "resident_evictions": 0}
 
     def _count(self, path: str, nbytes: Sequence[int]) -> None:
         with self._counts_lock:
@@ -211,7 +243,8 @@ class NumpyBackend(_PathCounts):
         return _merge_planes_host(planes, dtype_np, shape)
 
     def xor_delta_planes_batch(self, pairs):
-        return [self.xor_delta_planes(b, f) for b, f in pairs]
+        return [self.xor_delta_planes(_load_base(item[0]), item[1])
+                for item in pairs]
 
     def byte_planes_batch(self, xs):
         return [self.byte_planes(x) for x in xs]
@@ -222,16 +255,32 @@ class NumpyBackend(_PathCounts):
     def merge_planes_batch(self, items):
         return [self.merge_planes(p, d, s) for p, d, s in items]
 
+    def release_resident(self, hashes):
+        """The host path holds no base: nothing to release."""
+
 
 class JaxBackend(_PathCounts):
     """Device path over the Pallas kernels (``repro.kernels.ops``).
 
     Inputs are converted to their unsigned bit views host-side (so int8 and
-    bool-free integer tensors work without kernel-side dtype plumbing), then
-    the fused XOR+split / merge kernels run once per same-width bucket: a
-    batch of N same-dtype tensors is concatenated flat and transformed in a
-    single launch, and per-tensor planes are sliced back out — bit-identical
-    to the per-tensor host path because the transforms are elementwise.
+    bool-free integer tensors work without kernel-side dtype plumbing). The
+    XOR split runs one program per tensor shape: each fine-tune goes to the
+    device straight from its source view, against its base. The other
+    transforms run once per same-width bucket: a batch of N same-dtype
+    tensors is concatenated flat and transformed in a single launch, and
+    per-tensor planes are sliced back out — bit-identical to the per-tensor
+    host path because the transforms are elementwise.
+
+    A keyed encode item's base (``(base_loader, ft, base_hash, family)``)
+    stays on the device after its first use, keyed by its content hash and
+    bit-view dtype (so stale bytes can never be served); an unkeyed base is
+    sent for its one program and not held. Past
+    :func:`_resident_budget_bytes` a miss evicts the least recently used
+    bases of other families. A family never evicts its own: where its base
+    is larger than the budget, the tensors it put there first stay and the
+    rest are sent for each use, so a fine-tune that reads the base in the
+    same order every time still hits the part held. :meth:`release_resident`
+    drops held bases.
 
     On the CPU backend the kernels execute in interpret mode
     (`ops._interpret`), which is how the equivalence tests validate the
@@ -247,6 +296,11 @@ class JaxBackend(_PathCounts):
         super().__init__()
         self.use_pallas = use_pallas
         self._ops_mod = None
+        # (base hash, bit-view dtype) -> (device bit view, family), least
+        # recent first, and the bytes each family holds; guarded by the
+        # counts lock, as their counters are
+        self._resident: "OrderedDict[Tuple[str, str], Tuple[object, object]]" = OrderedDict()
+        self._family_bytes: Dict[object, int] = {}
 
     def _ops(self):
         # imported on first use: the host-only store (and the entropy
@@ -283,42 +337,99 @@ class JaxBackend(_PathCounts):
             groups.setdefault(np.dtype(d).str, []).append(i)
         return groups
 
+    # -- device-resident bases ----------------------------------------------
+    def _device_base(self, item, ft: np.ndarray):
+        """The base for encode item ``item``'s one program. A keyed item's
+        is the device view held, else it is loaded and put on the device,
+        held where :meth:`_admit` lets it; an unkeyed item's is its bit
+        view, which the program sends."""
+        key = None
+        if len(item) > 2:
+            key = (item[2], ft.dtype.str)
+            with self._counts_lock:
+                held = self._resident.get(key)
+                if held is not None:
+                    self._resident.move_to_end(key)
+                    self._counts["resident_hit_bytes"] += ft.nbytes
+                    return held[0]
+        a = _bit_view_np(np.ascontiguousarray(_load_base(item[0]))).reshape(-1)
+        assert a.shape == ft.shape and a.dtype == ft.dtype, \
+            (a.shape, ft.shape, a.dtype, ft.dtype)
+        if key is None:
+            return a
+        import jax
+        with obs.span("zllm.array.base_put", bytes=a.nbytes):
+            arr = jax.device_put(a, may_alias=False)
+        self._admit(key, item[3], arr)
+        return arr
+
+    def _admit(self, key: Tuple[str, str], family, arr) -> None:
+        """Count a miss and hold ``arr`` if its family's bases fit the
+        budget, evicting other families' least recently used ones."""
+        budget = _resident_budget_bytes()
+        with self._counts_lock:
+            self._counts["resident_miss_bytes"] += arr.nbytes
+            own = self._family_bytes.get(family, 0)
+            if key in self._resident or own + arr.nbytes > budget:
+                return
+            for old in [k for k, (_, f) in self._resident.items() if f != family]:
+                if self._counts["resident_bytes"] + arr.nbytes <= budget:
+                    break
+                self._drop(old)
+                self._counts["resident_evictions"] += 1
+            self._resident[key] = (arr, family)
+            self._family_bytes[family] = own + arr.nbytes
+            self._counts["resident_bytes"] += arr.nbytes
+
+    def _drop(self, key: Tuple[str, str]) -> None:
+        arr, family = self._resident.pop(key)
+        self._counts["resident_bytes"] -= arr.nbytes
+        self._family_bytes[family] -= arr.nbytes
+        if not self._family_bytes[family]:
+            del self._family_bytes[family]
+
+    def release_resident(self, hashes: Iterable[str]) -> None:
+        """Drop the resident base views of ``hashes``; a device array still
+        in use by a running encode lives until it ends."""
+        hashes = set(hashes)
+        with self._counts_lock:
+            for key in [k for k in self._resident if k[0] in hashes]:
+                self._drop(key)
+
     # Each batch call is one ``zllm.array.encode`` or ``zllm.array.decode``
-    # span; a device bucket in it is a ``zllm.array.concat`` (the host
-    # copies into one buffer), a ``zllm.array.device`` (the kernel call
-    # until its result is numpy: host-to-device, the programs,
-    # device-to-host) and a ``zllm.array.slice`` (the per-tensor copies).
+    # span. An encode is a ``zllm.array.base_put`` for each keyed base not
+    # held, then one ``zllm.array.device`` for all its programs (until their
+    # results are numpy: host-to-device, the programs, device-to-host). A
+    # bucket of the other transforms is a ``zllm.array.concat`` (the host
+    # copies into one buffer), a ``zllm.array.device`` and a
+    # ``zllm.array.slice`` (the per-tensor copies).
     def xor_delta_planes_batch(self, pairs):
         out: List[Optional[List[np.ndarray]]] = [None] * len(pairs)
         with obs.span("zllm.array.encode") as sp:
-            views = []
-            for base, ft in pairs:
-                a = _bit_view_np(np.ascontiguousarray(base)).reshape(-1)
-                b = _bit_view_np(np.ascontiguousarray(ft)).reshape(-1)
-                assert a.shape == b.shape and a.dtype == b.dtype, \
-                    (a.shape, b.shape, a.dtype, b.dtype)
-                views.append((a, b))
-            sp.set(bytes=sum(b.nbytes for _, b in views))
-            for dstr, idxs in self._buckets([v[0].dtype for v in views]).items():
-                if not self._device_ok(np.dtype(dstr)):
-                    self._count("host", [views[i][0].nbytes for i in idxs])
-                    for i in idxs:
-                        out[i] = _xor_delta_planes_host(*views[i])
-                    continue
-                self._count("device", [views[i][0].nbytes for i in idxs])
-                with obs.span("zllm.array.concat"):
-                    cat_a = np.concatenate([views[i][0] for i in idxs])
-                    cat_b = np.concatenate([views[i][1] for i in idxs])
-                with obs.span("zllm.array.device", bytes=cat_b.nbytes):
-                    planes = [np.asarray(p) for p in self._ops().bitx_encode_planes(
-                        cat_a, cat_b, use_pallas=self.use_pallas)]
-                with obs.span("zllm.array.slice"):
-                    off = 0
-                    for i in idxs:
-                        n = views[i][0].size
-                        out[i] = [np.ascontiguousarray(p[off:off + n])
-                                  for p in planes]
-                        off += n
+            fts = [_bit_view_np(np.ascontiguousarray(item[1])).reshape(-1)
+                   for item in pairs]
+            sp.set(bytes=sum(b.nbytes for b in fts))
+            dev = [i for i, b in enumerate(fts) if self._device_ok(b.dtype)]
+            host = [i for i, b in enumerate(fts) if not self._device_ok(b.dtype)]
+            self._count("host", [fts[i].nbytes for i in host])
+            for i in host:
+                base = _bit_view_np(np.ascontiguousarray(_load_base(pairs[i][0])))
+                out[i] = _xor_delta_planes_host(base.reshape(-1), fts[i])
+            if not dev:
+                return out
+            self._count("device", [fts[i].nbytes for i in dev])
+            bases = [self._device_base(pairs[i], fts[i]) for i in dev]
+            encode = self._ops().bitx_encode_planes
+            with obs.span("zllm.array.device", bytes=sum(fts[i].nbytes for i in dev)):
+                # all dispatched before any result is read, so the transfers
+                # of one tensor overlap the programs of the next
+                results = [encode(a, fts[i], use_pallas=self.use_pallas)
+                           for a, i in zip(bases, dev)]
+                for planes in results:
+                    for p in planes:
+                        p.copy_to_host_async()
+                for i, planes in zip(dev, results):
+                    out[i] = [np.asarray(p) for p in planes]
         return out
 
     def byte_planes_batch(self, xs):

@@ -154,7 +154,7 @@ from collections import OrderedDict, deque
 from concurrent.futures import Future, ProcessPoolExecutor, ThreadPoolExecutor
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 import zstandard as zstd
@@ -681,6 +681,9 @@ class ZLLMStore:
                                        on_evict=self._retire_reader)
         self._tensor_cache = _LRUCache(max_items=4096, max_bytes=tensor_cache_bytes)
         self._base_maps: Dict[str, _BaseTensorMap] = {}
+        # base tensor hashes this store passed to the backend as keyed bitx
+        # items: what it asks the backend to release on close
+        self._resident_hashes: Set[str] = set()
         # parsed name->(idx, dtype, shape) maps of near-dup headers, keyed by
         # the entry's pinned target + content hash (tensor-granular serving
         # must not re-parse the header blob per request)
@@ -790,6 +793,8 @@ class ZLLMStore:
         for bm in {id(m): m for m in self._base_maps.values()}.values():
             bm.close()
         self._base_maps.clear()
+        self.backend.release_resident(self._resident_hashes)
+        self._resident_hashes.clear()
 
     def __enter__(self) -> "ZLLMStore":
         return self
@@ -1381,10 +1386,10 @@ class ZLLMStore:
         infos = sf.infos
         # device-batched lane (batching backends only): bitx/zipnn tensors
         # get a placeholder Future in the plan and their array stage runs in
-        # dtype-bucketed fused launches at flush time; decisions (this loop)
+        # the backend's batch calls at flush time; decisions (this loop)
         # stay strictly serial either way, so containers are bit-identical
         batching = self.backend.supports_batching
-        batch: List[Tuple[Future, str, Any, Any]] = []
+        batch: List[Tuple[Future, str, Any, Any, Optional[str]]] = []
         batch_bytes = 0
         for i, ti in enumerate(infos):
             res.n_tensors += 1
@@ -1429,10 +1434,10 @@ class ZLLMStore:
                     res.n_raw += 1
                 if batching and kind in ("bitx", "zipnn"):
                     payload: Any = Future()
-                    batch.append((payload, kind, ti, base_loader))
+                    batch.append((payload, kind, ti, base_loader, base_hash))
                     batch_bytes += ti.nbytes
                     if batch_bytes >= _DEVICE_BATCH_MAX_BYTES:
-                        self._flush_device_batch(sf, key, batch, pool, epool)
+                        self._flush_device_batch(sf, key, res.base_id, batch, pool, epool)
                         batch, batch_bytes = [], 0
                 else:
                     job = self._encode_job(self._codec_runtime, kind, sf, key,
@@ -1447,42 +1452,46 @@ class ZLLMStore:
             # Record index == tensor index (dedup entries are records too).
             self.tensor_locations.setdefault(thash, (key, gen, i))
         if batch:
-            self._flush_device_batch(sf, key, batch, pool, epool)
+            self._flush_device_batch(sf, key, res.base_id, batch, pool, epool)
 
-    def _flush_device_batch(self, sf, key: str,
-                            batch: List[Tuple[Future, str, Any, Any]],
+    def _flush_device_batch(self, sf, key: str, base_id: Optional[str],
+                            batch: List[Tuple[Future, str, Any, Any, Optional[str]]],
                             pool, epool) -> None:
-        """Run the array stage of the accumulated bitx/zipnn tensors in
-        dtype-bucketed fused kernel launches (one per bit-width bucket), then
-        fan the per-tensor entropy stage back out across the pool. Each
-        placeholder resolves to the same ``(codec, frames, raw_size)`` tuple
-        the unbatched encode job produces — the transforms are elementwise,
-        so the plane bytes (hence the container bytes) are identical."""
+        """Run the array stage of the accumulated bitx/zipnn tensors in one
+        batch call per kind, then fan the per-tensor entropy stage back out
+        across the pool. Each placeholder resolves to the same ``(codec,
+        frames, raw_size)`` tuple the unbatched encode job produces — the
+        transforms are elementwise, so the plane bytes (hence the container
+        bytes) are identical.
+        BitX items name their base by content hash and by ``base_id``, its
+        family, and pass its loader, so a backend that holds the base
+        already never reads it again."""
         try:
             arrs = [np.frombuffer(sf.tensor_bytes(ti.name),
                                   STR_TO_DTYPE[ti.dtype_str]).reshape(ti.shape)
-                    for _, _, ti, _ in batch]
+                    for _, _, ti, _, _ in batch]
             planes_of: List[Any] = [None] * len(batch)
-            xor_idx = [i for i, (_, kind, _, _) in enumerate(batch) if kind == "bitx"]
-            pln_idx = [i for i, (_, kind, _, _) in enumerate(batch) if kind == "zipnn"]
+            xor_idx = [i for i, b in enumerate(batch) if b[1] == "bitx"]
+            pln_idx = [i for i, b in enumerate(batch) if b[1] == "zipnn"]
             if xor_idx:
-                pairs = [(batch[i][3]().reshape(-1), arrs[i].reshape(-1))
+                items = [(batch[i][3], arrs[i].reshape(-1), batch[i][4], base_id)
                          for i in xor_idx]
+                self._resident_hashes.update(batch[i][4] for i in xor_idx)
                 for i, planes in zip(xor_idx,
-                                     self.backend.xor_delta_planes_batch(pairs)):
+                                     self.backend.xor_delta_planes_batch(items)):
                     planes_of[i] = planes
             if pln_idx:
                 split = self.backend.byte_planes_batch([arrs[i] for i in pln_idx])
                 for i, planes in zip(pln_idx, split):
                     planes_of[i] = planes
         except BaseException as e:
-            for fut, _, _, _ in batch:
+            for fut, *_ in batch:
                 if not fut.done():
                     fut.set_exception(e)
             raise
         # entropy stage: planes are private copies (the kernel outputs), so
         # these jobs never touch the source mmap and may outlive the plan
-        for (fut, kind, ti, _), arr, planes in zip(batch, arrs, planes_of):
+        for (fut, kind, ti, _, _), arr, planes in zip(batch, arrs, planes_of):
             job = self._entropy_job(kind, key, planes, int(arr.nbytes), epool)
             if pool is not None and ti.nbytes >= _PARALLEL_MIN_BYTES:
                 self._chain_future(pool.submit(job), fut)
@@ -1668,8 +1677,12 @@ class ZLLMStore:
         The next fine-tune ingest rebuilds from disk with one hash pass."""
         ids = [base_id] if base_id is not None else list(self._base_maps)
         for bid in ids:
-            if self._base_maps.pop(bid, None) is not None:
+            bm = self._base_maps.pop(bid, None)
+            if bm is not None:
                 self.base_map_stats["invalidations"] += 1
+                hashes = {t[3] for t in bm.tensors.values()}
+                self.backend.release_resident(hashes)
+                self._resident_hashes.difference_update(hashes)
 
     def _base_tensor_map(self, base_id: str) -> Dict[str, Tuple]:
         """name -> (dtype_str, shape, lazy loader, tensor hash) for the base."""
