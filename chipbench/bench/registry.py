@@ -1,10 +1,16 @@
-"""Find a cell's configuration, traffic mix, peaks and metric readers by name.
+"""Find a cell's configuration, layout, traffic mix, peaks and metric readers
+by name.
 
-Everything that belongs to one configuration, one traffic mix or one metric
-sits in a file of its own; adding one needs only a new file and its entry
-in ``BENCHMARK.json``:
+Everything that belongs to one configuration, one architecture, one traffic
+mix or one metric sits in a file of its own; adding one needs only new files
+and their entries in ``BENCHMARK.json``:
 
-- ``configs/<config>.json``: the file that ``BENCHMARK.json`` names;
+- ``configs/<config>.json``: the file that ``BENCHMARK.json`` names, with
+  the published ``config.json``'s keys; its ``quantization_config``, where
+  it has one, says how the weights are stored (``bench/spec.py``);
+- ``layouts/<model_type>.py``: ``layer_tensors(cfg) -> List[Tensor]``, the
+  tensors of the configuration's decoder layers in Hugging Face naming and
+  module order, found by the configuration's ``model_type``;
 - ``traffic/<traffic>.json``: the parameters the general generator reads;
 - ``metrics/<metric>.py``: a reader with ``read(run) -> float | None``.
 """
@@ -62,11 +68,24 @@ def metrics_for(bm: Dict, cell_name: str, traced: bool) -> List[Dict]:
     return [m for m in group if cell_name in m.get("workloads", [cell_name])]
 
 
-def reader(metric_name: str):
-    """The ``read`` function of ``metrics/<metric_name>.py``."""
-    path = os.path.join(BENCH_DIR, "metrics", metric_name + ".py")
+def _module(kind: str, name: str):
+    """The module ``<kind>/<name>.py``; a missing file is an error that
+    names it."""
+    path = os.path.join(BENCH_DIR, kind, name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no {kind} file for {name!r}: {path} is missing")
     spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + metric_name.replace(".", "_").replace("-", "_"), path)
+        f"chipbench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric_name: str):
+    """The ``read`` function of ``metrics/<metric_name>.py``."""
+    return _module("metrics", metric_name).read
+
+
+def layout(model_type: str):
+    """The ``layer_tensors`` function of ``layouts/<model_type>.py``."""
+    return _module("layouts", model_type).layer_tensors

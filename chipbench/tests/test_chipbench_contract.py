@@ -8,7 +8,7 @@ import re
 import pytest
 
 import tinycell
-from bench import harness, registry
+from bench import harness, registry, spec
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -56,13 +56,27 @@ def test_registry_finds_new_files_by_name(tmp_path, monkeypatch):
         json.dumps({"clients": 4, "uploads_per_client": 9}))
     (tmp_path / "metrics" / "answer.read.py").write_text(
         "def read(run):\n    return 42.0\n")
-    (tmp_path / "configs" / "new.json").write_text(json.dumps(tinycell.DENSE))
+    (tmp_path / "configs" / "new.json").write_text(
+        json.dumps(dict(tinycell.DENSE, model_type="new_arch")))
+    (tmp_path / "layouts").mkdir()
+    (tmp_path / "layouts" / "new_arch.py").write_text(
+        "from bench.spec import Tensor\n\n\n"
+        "def layer_tensors(cfg):\n"
+        "    return [Tensor('w', (cfg['hidden_size'],), 'F32')]\n")
     monkeypatch.setattr(registry, "BENCH_DIR", str(tmp_path))
     monkeypatch.setattr(registry, "REPO", str(tmp_path))
     bm = {"configs": [{"name": "new", "file": "configs/new.json"}]}
     assert registry.traffic("burst-ingest")["uploads_per_client"] == 9
     assert registry.reader("answer.read")(None) == 42.0
-    assert registry.config(bm, "new")["hidden_size"] == 256
+    cfg = registry.config(bm, "new")
+    assert cfg["hidden_size"] == 256
+    assert spec.layer_tensors(cfg) == [spec.Tensor("w", (256,), "F32")]
+
+
+def test_unknown_model_type_names_the_missing_layout():
+    missing = os.path.join(registry.BENCH_DIR, "layouts", "no_such_arch.py")
+    with pytest.raises(FileNotFoundError, match=re.escape(missing)):
+        spec.layer_tensors(dict(tinycell.DENSE, model_type="no_such_arch"))
 
 
 class _Run:
